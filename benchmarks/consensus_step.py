@@ -75,9 +75,11 @@ artifact tracked from PR 2 onward) plus a copy under
     error than flat, or the hierarchical step tracing more than the
     2 ring ppermutes of the outer exchange.
 
-Run standalone (sets up its own host devices):
+It is a forced-host-device benchmark by design: it runs on four CPU
+devices and never on an accelerator.  Run standalone (sets up its own
+host devices):
 
-    PYTHONPATH=src python -m benchmarks.consensus_step
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.consensus_step
 """
 from __future__ import annotations
 
@@ -107,8 +109,7 @@ from repro.core.distributed import (ConsensusConfig,         # noqa: E402
                                     ConsensusRuntime)
 from repro.models import transformer as T                    # noqa: E402
 from repro.models.params import ParamDef, local_block_shape  # noqa: E402
-from repro.models.sharding import (ParallelContext,          # noqa: E402
-                                   shard_map_compat)
+from repro.models.sharding import ParallelContext             # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCHS = ("smollm-135m", "qwen3-0.6b")
@@ -302,8 +303,8 @@ def build_step(rt: ConsensusRuntime, mesh, tree):
     def init(p):
         return jax.tree.map(lambda a: a[None], rt.init_state(p))
 
-    init_f = jax.jit(shard_map_compat(init, mesh, in_specs=(pspec,),
-                                      out_specs=cons_spec, check=False))
+    init_f = jax.jit(jax.shard_map(init, mesh=mesh, in_specs=(pspec,),
+                                   out_specs=cons_spec, check_vma=False))
 
     def step(xp, xh, st, noise, k):
         st = jax.tree.map(lambda a: a[0], st)
@@ -311,9 +312,9 @@ def build_step(rt: ConsensusRuntime, mesh, tree):
                                      noise=noise[0])
         return x_next, jax.tree.map(lambda a: a[None], st2)
 
-    step_f = jax.jit(shard_map_compat(
-        step, mesh, in_specs=(pspec, pspec, cons_spec, noise_spec, P()),
-        out_specs=(pspec, cons_spec), check=False))
+    step_f = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(pspec, pspec, cons_spec, noise_spec, P()),
+        out_specs=(pspec, cons_spec), check_vma=False))
     return init_f, step_f
 
 
@@ -363,8 +364,8 @@ def build_step_metrics(rt: ConsensusRuntime, mesh, tree):
     def init(p):
         return jax.tree.map(lambda a: a[None], rt.init_state(p))
 
-    init_f = jax.jit(shard_map_compat(init, mesh, in_specs=(pspec,),
-                                      out_specs=cons_spec, check=False))
+    init_f = jax.jit(jax.shard_map(init, mesh=mesh, in_specs=(pspec,),
+                                   out_specs=cons_spec, check_vma=False))
 
     def step(xp, xh, st, noise, k):
         st = jax.tree.map(lambda a: a[0], st)
@@ -373,9 +374,9 @@ def build_step_metrics(rt: ConsensusRuntime, mesh, tree):
         return (x_next, jax.tree.map(lambda a: a[None], st2),
                 m["residual_norm"][None], m["overflow_frac"][None])
 
-    step_f = jax.jit(shard_map_compat(
-        step, mesh, in_specs=(pspec, pspec, cons_spec, noise_spec, P()),
-        out_specs=(pspec, cons_spec, P("data"), P("data")), check=False))
+    step_f = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(pspec, pspec, cons_spec, noise_spec, P()),
+        out_specs=(pspec, cons_spec, P("data"), P("data")), check_vma=False))
     return init_f, step_f
 
 
@@ -627,8 +628,8 @@ def _build_loss_step(rt: ConsensusRuntime, mesh, tree):
     def init(p):
         return jax.tree.map(lambda a: a[None], rt.init_state(p))
 
-    init_f = jax.jit(shard_map_compat(init, mesh, in_specs=(pspec,),
-                                      out_specs=cons_spec, check=False))
+    init_f = jax.jit(jax.shard_map(init, mesh=mesh, in_specs=(pspec,),
+                                   out_specs=cons_spec, check_vma=False))
 
     def step(xp, xh, st, noise, k):
         st = jax.tree.map(lambda a: a[0], st)
@@ -638,9 +639,9 @@ def _build_loss_step(rt: ConsensusRuntime, mesh, tree):
         return (x_next, jax.tree.map(lambda a: a[None], st2),
                 delivered[None])
 
-    step_f = jax.jit(shard_map_compat(
-        step, mesh, in_specs=(pspec, pspec, cons_spec, noise_spec, P()),
-        out_specs=(pspec, cons_spec, P("data")), check=False))
+    step_f = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(pspec, pspec, cons_spec, noise_spec, P()),
+        out_specs=(pspec, cons_spec, P("data")), check_vma=False))
     return init_f, step_f
 
 
@@ -770,8 +771,8 @@ def _build_churn_step(rt: ConsensusRuntime, mesh, tree):
     def init(p):
         return jax.tree.map(lambda a: a[None], rt.init_state(p))
 
-    init_f = jax.jit(shard_map_compat(init, mesh, in_specs=(pspec,),
-                                      out_specs=cons_spec, check=False))
+    init_f = jax.jit(jax.shard_map(init, mesh=mesh, in_specs=(pspec,),
+                                   out_specs=cons_spec, check_vma=False))
 
     def step(xp, xh, st, noise, k):
         st = jax.tree.map(lambda a: a[0], st)
@@ -781,9 +782,9 @@ def _build_churn_step(rt: ConsensusRuntime, mesh, tree):
         return (x_next, jax.tree.map(lambda a: a[None], st2),
                 delivered[None])
 
-    step_f = jax.jit(shard_map_compat(
-        step, mesh, in_specs=(pspec, pspec, cons_spec, noise_spec, P()),
-        out_specs=(pspec, cons_spec, P("data")), check=False))
+    step_f = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(pspec, pspec, cons_spec, noise_spec, P()),
+        out_specs=(pspec, cons_spec, P("data")), check_vma=False))
     return init_f, step_f
 
 
@@ -982,8 +983,8 @@ def _build_overlap_step(rt: ConsensusRuntime, mesh, tree, iters: int):
     def init(p):
         return jax.tree.map(lambda a: a[None], rt.init_state(p))
 
-    init_f = jax.jit(shard_map_compat(init, mesh, in_specs=(pspec,),
-                                      out_specs=cons_spec, check=False))
+    init_f = jax.jit(jax.shard_map(init, mesh=mesh, in_specs=(pspec,),
+                                   out_specs=cons_spec, check_vma=False))
 
     def step(xp, xh, st, noise, z, k):
         st = jax.tree.map(lambda a: a[0], st)
@@ -992,10 +993,10 @@ def _build_overlap_step(rt: ConsensusRuntime, mesh, tree, iters: int):
                                      noise=noise[0])
         return x_next, jax.tree.map(lambda a: a[None], st2), z2[None]
 
-    step_f = jax.jit(shard_map_compat(
-        step, mesh,
+    step_f = jax.jit(jax.shard_map(
+        step, mesh=mesh,
         in_specs=(pspec, pspec, cons_spec, noise_spec, z_spec, P()),
-        out_specs=(pspec, cons_spec, z_spec), check=False))
+        out_specs=(pspec, cons_spec, z_spec), check_vma=False))
     return init_f, step_f
 
 
@@ -1040,17 +1041,17 @@ def overlap_section(mesh, ctx) -> tuple[dict, bool]:
     z_spec = P("data", None, None)
     compute_f = {}
     for iters in {OVERLAP_CAL_ITERS}:
-        compute_f[iters] = jax.jit(shard_map_compat(
-            lambda z, it=iters: _synth_compute(z[0], it)[None], mesh,
-            in_specs=(z_spec,), out_specs=z_spec, check=False))
+        compute_f[iters] = jax.jit(jax.shard_map(
+            lambda z, it=iters: _synth_compute(z[0], it)[None], mesh=mesh,
+            in_specs=(z_spec,), out_specs=z_spec, check_vma=False))
     t_cal = _median_steps(compute_f[OVERLAP_CAL_ITERS], (z0,))
     per_iter = t_cal["seconds_per_step"] / OVERLAP_CAL_ITERS
     iters = int(np.clip(round(
         OVERLAP_TARGET_RATIO * t_exch["seconds_per_step"] / per_iter),
         OVERLAP_MIN_ITERS, OVERLAP_MAX_ITERS))
-    compute_f[iters] = jax.jit(shard_map_compat(
-        lambda z: _synth_compute(z[0], iters)[None], mesh,
-        in_specs=(z_spec,), out_specs=z_spec, check=False))
+    compute_f[iters] = jax.jit(jax.shard_map(
+        lambda z: _synth_compute(z[0], iters)[None], mesh=mesh,
+        in_specs=(z_spec,), out_specs=z_spec, check_vma=False))
     t_comp = _median_steps(compute_f[iters], (z0,))
     out = {"tree_params": layout.n_elements, "mm_dim": OVERLAP_MM_DIM,
            "synth_iters": iters, "target_ratio": OVERLAP_TARGET_RATIO,
@@ -1280,10 +1281,12 @@ def append_run(path: str, payload: dict, ok: bool) -> dict:
 
 
 def main() -> int:
-    if jax.device_count() < N_DEVICES:
-        print(f"SKIP: need >= {N_DEVICES} devices, have {jax.device_count()} "
-              "(set XLA_FLAGS=--xla_force_host_platform_device_count=4)")
-        return 0
+    if jax.default_backend() != "cpu" or jax.device_count() < N_DEVICES:
+        print(f"FAIL: need >= {N_DEVICES} CPU devices, have "
+              f"{jax.device_count()} {jax.default_backend()} device(s) (set "
+              "JAX_PLATFORMS=cpu and "
+              "XLA_FLAGS=--xla_force_host_platform_device_count=4)")
+        return 1
     mesh = Mesh(np.array(jax.devices()[:N_DEVICES]), ("data",))
     ctx = ParallelContext(tp=1, data_size=N_DEVICES, n_nodes=N_DEVICES,
                           in_shard_map=True)

@@ -545,17 +545,18 @@ def bench_llm_wire_bytes() -> None:
 
 def bench_consensus_step_latency() -> None:
     """Per-leaf vs packed vs pipelined consensus exchange on real LLM leaf
-    trees (see benchmarks/consensus_step.py).  Runs in a subprocess so the
-    >=4-device host platform does not clash with this process's jax device
-    state; fails (raises) on any smoke gate: packed slower than per-leaf,
-    pipelined best-chunk slower than packed, or packed compile time over
-    its trace-size budget."""
+    trees (see benchmarks/consensus_step.py).  Runs in a subprocess on four
+    forced host CPU devices (``JAX_PLATFORMS=cpu``), so it never competes
+    with this process for an accelerator; fails (raises) on any smoke gate:
+    packed slower than per-leaf, pipelined best-chunk slower than packed,
+    or packed compile time over its trace-size budget."""
     import subprocess
     import sys
     t0 = time.time()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(repo, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     proc = subprocess.run([sys.executable, "-m", "benchmarks.consensus_step"],
                           capture_output=True, text=True, cwd=repo, env=env,
@@ -563,12 +564,6 @@ def bench_consensus_step_latency() -> None:
     if proc.returncode != 0:
         raise RuntimeError(f"consensus_step failed:\n{proc.stdout[-2000:]}\n"
                            f"{proc.stderr[-2000:]}")
-    first_line = proc.stdout.splitlines()[0] if proc.stdout else ""
-    if first_line.startswith("SKIP"):
-        # the subprocess could not create the >=4-device host mesh (e.g. a
-        # non-CPU jax backend); it writes no JSON — do not read a stale one
-        _row("consensus_step_latency", time.time() - t0, first_line)
-        return
     with open(os.path.join(repo, "BENCH_consensus_step.json")) as f:
         series = json.load(f)
     runs = (series["runs"] if isinstance(series.get("runs"), list)

@@ -5,8 +5,10 @@ synchronization between consensus nodes goes over SLOW links, so the
 parameter exchanges are int8-compressed amplified differentials (the
 paper's Algorithm 2) instead of fp32 all-reduce.
 
-This driver runs on the CPU container with 8 host devices emulating the
-mesh: 4 data rows x 2 model columns, 2 consensus nodes x 2-way FSDP.
+This driver runs on the CPU, with 8 host devices emulating the mesh: 4
+data rows x 2 model columns, 2 consensus nodes x 2-way FSDP.  It sets
+JAX_PLATFORMS=cpu itself, so on an accelerator host it still runs on the
+host devices (``chip_smoke.py`` drives the train step on a TPU).
 It trains a reduced SmolLM-family model for a few hundred steps and
 compares against uncompressed DGD and classic all-reduce, reporting loss,
 consensus error and wire bytes.
@@ -18,6 +20,8 @@ Run:
 """
 import os
 
+# the 8-device mesh is made of host CPU devices
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse

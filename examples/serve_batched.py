@@ -1,6 +1,8 @@
 """Batched serving: prefill + greedy decode with a sharded KV/SSM cache.
 
-Serves a reduced model on the 8-device CPU mesh (2 data x 4 model):
+Serves a reduced model on an 8-device mesh of host CPU devices (2 data x
+4 model; the script sets JAX_PLATFORMS=cpu itself, so it runs on the host
+devices on an accelerator host too):
   1. prefill a batch of prompts (builds the sharded decode cache),
   2. decode N tokens autoregressively with single-token serve steps.
 
@@ -13,6 +15,8 @@ Run:
 """
 import os
 
+# the 8-device mesh is made of host CPU devices
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse

@@ -13,7 +13,7 @@ the paper targets.
 Per step k (paper Algorithm 2, k^gamma folded into the quantizer step —
 DESIGN.md §Hardware adaptation):
 
-    y_i   = x_i^{k+1/2} - x_tilde_i          (x^{k+1/2} = after local opt step)
+    y_i   = x_i^k - x_tilde_i                (x^{k+1/2} = after local opt step)
     codes = StochasticQuant(y_i; step_k)      step_k = step0 / k^gamma (fixed
                                               mode) or per-block max (adaptive)
     ppermute codes+scales to ring neighbors (int8 wire)
@@ -1176,7 +1176,9 @@ class ConsensusRuntime:
 
         xt = state["x_tilde"]                       # (n_rows, BLOCK) packed
         mb = state["m_agg"]
-        xh_p = layout.pack(x_half)
+        # the shadows estimate the iterate x^k, not x^{k+1/2}: the local
+        # step is added once, after the combine (paper Algorithm 2)
+        xp_p = layout.pack(x_prev)
         if push:
             # numerator domain: the wire carries w_i * x_i and the weight
             # scalar; both are mixed by the same column-stochastic W and
@@ -1184,10 +1186,10 @@ class ConsensusRuntime:
             # At w == 1 the multiply is a bitwise identity, so the
             # symmetric exactness contracts survive unchanged.
             ps_w = state["ps_w"]                    # (1,) fp32
-            xh_p = xh_p * ps_w[0]
+            xp_p = xp_p * ps_w[0]
             trailer = jax.lax.bitcast_convert_type(
                 ps_w.astype(jnp.float32), jnp.uint8).reshape(-1)
-        y = xh_p - xt                               # packed differential
+        y = xp_p - xt                               # packed differential
         if noise is None:
             # ONE noise buffer sized for the plan's widest codec (top-k
             # consumes a second BLOCK-wide region for its selection race);
@@ -1756,12 +1758,12 @@ class ConsensusRuntime:
         for i, (leaf_half, leaf_prev) in enumerate(zip(leaves, prev_leaves)):
             slot = layout.slots[i]
             full = kops.padded_block_rows(slot.size)
-            xh_b = kops.blockify(leaf_half.astype(jnp.float32).reshape(-1))
+            xp_b = kops.blockify(leaf_prev.astype(jnp.float32).reshape(-1))
             if push:
-                xh_b = xh_b * ps_w[0]       # numerator domain (cf. packed)
+                xp_b = xp_b * ps_w[0]       # numerator domain (cf. packed)
             xtb = rowpad(layout.leaf_rows(state["x_tilde"], i), full)
             mb = rowpad(layout.leaf_rows(state["m_agg"], i), full)
-            yb = xh_b - xtb
+            yb = xp_b - xtb
             residual_sq = residual_sq + jnp.sum(yb * yb)
             if noise is None:       # historical per-leaf noise stream
                 noise_b = jax.random.uniform(leaf_keys[i], yb.shape,

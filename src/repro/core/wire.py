@@ -48,48 +48,26 @@ __all__ = ["LeafSlot", "WireLayout", "ChunkedLayout", "pvary_to",
 def pvary_to(x, axes):
     """Mark ``x`` vma-varying over ``axes`` (no-op semantically; required so
     shard_map(check_vma=True) out_specs naming those axes type-check even
-    when no leaf of the packed tree happened to vary on one of them).
-    No-op on jax versions without the vma system."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return x
-    have = getattr(typeof(x), "vma", frozenset()) or frozenset()
+    when no leaf of the packed tree happened to vary on one of them)."""
+    have = jax.typeof(x).vma
     missing = tuple(a for a in axes if a is not None and a not in have)
-    return jax.lax.pcast(x, missing, to="varying") if missing else x
+    return jax.lax.pcast(x, missing, to="varying")
 
 
 def _lift_common_vma(arrays):
     """pcast every array to the union vma of the group before concatenation
     (shard_map check_vma=True requires concat operands uniformly typed; a
-    no-op outside shard_map and on jax versions without the vma system)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return list(arrays)
-    union: frozenset = frozenset()
-    for a in arrays:
-        union |= getattr(typeof(a), "vma", frozenset()) or frozenset()
-    if not union:
-        return list(arrays)
-    out = []
-    for a in arrays:
-        have = getattr(typeof(a), "vma", frozenset()) or frozenset()
-        missing = tuple(union - have)
-        out.append(jax.lax.pcast(a, missing, to="varying") if missing else a)
-    return out
+    no-op outside shard_map)."""
+    union = frozenset().union(*(jax.typeof(a).vma for a in arrays))
+    return [jax.lax.pcast(a, tuple(union - jax.typeof(a).vma), to="varying")
+            for a in arrays]
 
 
 def _flatten_with_paths(tree):
-    """(leaves, treedef, path strings) — path strings via keystr where this
-    jax has tree_flatten_with_path (>= 0.4.6); positional fallbacks
-    (``leaf[i]``) otherwise so WirePlan rules degrade, never crash."""
-    flatten_wp = getattr(jax.tree_util, "tree_flatten_with_path", None)
-    if flatten_wp is not None:
-        keyed, treedef = flatten_wp(tree)
-        keystr = getattr(jax.tree_util, "keystr", lambda kp: str(kp))
-        return ([leaf for _, leaf in keyed], treedef,
-                [keystr(kp) for kp, _ in keyed])
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    return leaves, treedef, [f"leaf[{i}]" for i in range(len(leaves))]
+    """(leaves, treedef, path strings via ``jax.tree_util.keystr``)."""
+    keyed, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    return ([leaf for _, leaf in keyed], treedef,
+            [jax.tree_util.keystr(kp) for kp, _ in keyed])
 
 
 def lift_concat(parts, axis: int = 0):

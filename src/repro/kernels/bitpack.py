@@ -28,7 +28,7 @@ operands) as the int8 kernels, reused verbatim.
 
 The jnp reference path and the Pallas kernels share the *same* core
 functions (`_subbyte_encode_core` etc.), so ref == interpret == compiled is
-structural, not a re-derivation (vma lifts are no-ops outside shard_map).
+structural, not a re-derivation.
 """
 from __future__ import annotations
 
@@ -37,10 +37,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .quantize import (BLOCK, TILE_N, _align_vma, _chunk_view, _lit,
-                       _match_vma, _out_vma, _row_index_map,
-                       default_interpret)
+from .quantize import (BLOCK, SMEM_SCALARS, TILE_N, _align_vma, _chunk_view,
+                       _out_vma, _row_index_map, default_interpret)
 
 __all__ = [
     "SUB_SCALE_BYTES", "subbyte_code_max", "subbyte_pack",
@@ -85,16 +85,24 @@ def topk_payload_width(block: int, k: int) -> int:
 
 def _bf16_round(scale):
     """Round the per-row scale to bf16 precision (the wire precision) BEFORE
-    it is used for rounding — encode and decode then share one exact grid."""
-    return scale.astype(jnp.bfloat16).astype(jnp.float32)
+    it is used for rounding — encode and decode then share one exact grid.
+
+    Round-to-nearest-even on the f32 bit pattern, in int32: XLA on a TPU
+    may drop an f32 -> bf16 -> f32 convert pair inside a fusion (excess
+    precision), which left the reference's scales unrounded while the
+    kernels' were rounded.  Integer ops are computed as written everywhere.
+    Finite inputs."""
+    u = jax.lax.bitcast_convert_type(scale, jnp.int32)
+    u = (u + 0x7FFF + ((u >> 16) & 1)) & -0x10000
+    return jax.lax.bitcast_convert_type(u, jnp.float32)
 
 
-def _sr_clip(s, noise, code_max, like):
+def _sr_clip(s, noise, code_max):
     """Stochastic round + clip to the symmetric code range."""
     lo = jnp.floor(s)
     frac = s - lo
     q = lo + (noise < frac).astype(jnp.float32)
-    return jnp.clip(q, _lit(-float(code_max), like), _lit(float(code_max), like))
+    return jnp.clip(q, -float(code_max), float(code_max))
 
 
 def _row_scale(y, step, code_max):
@@ -111,16 +119,81 @@ def _row_scale(y, step, code_max):
     """
     if step is None:
         absmax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
-        absmax = _match_vma(absmax, y)   # reductions strip vma
-        scale = jnp.maximum(absmax, _lit(1e-30, y)) \
-            * _lit(1.0 / code_max, y)
+        scale = jnp.maximum(absmax, 1e-30) * jnp.float32(1.0 / code_max)
         s_near = _bf16_round(scale)
-        s_up = _bf16_round(s_near * _lit(1.0 + 2.0 ** -7, s_near))
+        s_up = _bf16_round(s_near * jnp.float32(1.0 + 2.0 ** -7))
         return jnp.where(s_near < scale, s_up, s_near)
     return _bf16_round(jnp.broadcast_to(step, (y.shape[0], 1)))
 
 
-def _pack_fields(q, code_max, pack):
+# Mosaic lowers no float->uint cast, no reduction over unsigned integers
+# and no reshape that splits the lane dimension.  Byte fields are therefore
+# formed in f32/int32 (every value fits in the low byte, so the payload
+# bytes are the same), lane groups are reduced by an XOR butterfly of lane
+# rotations, and groups of lanes are compacted by one bf16 MXU dot inside a
+# kernel (``kernel=True``) or by a reshape-sum in XLA.
+
+def _bytes_to_i32(code_bytes):
+    """uint8 -> int32 in [0, 255] (same-width bitcast, sign-extend, mask)."""
+    return jax.lax.bitcast_convert_type(
+        code_bytes, jnp.int8).astype(jnp.int32) & 0xFF
+
+
+def _expand_groups(x, g):
+    """(R, n) -> (R, n * g): each lane repeated over a group of g lanes."""
+    r, n = x.shape
+    return jnp.broadcast_to(x[:, :, None], (r, n, g)).reshape(r, n * g)
+
+
+def _lane_roll(x, shift, kernel):
+    """``jnp.roll(x, shift, axis=-1)``; a native lane rotation in a kernel."""
+    if kernel:
+        return pltpu.roll(x, shift % x.shape[-1], x.ndim - 1)
+    return jnp.roll(x, shift, axis=-1)
+
+
+def _group_reduce(x, g, op, kernel):
+    """``op``-reduce over aligned groups of ``g`` (a power of two)
+    contiguous lanes, the result broadcast to every lane of its group.
+
+    XOR butterfly: at distance d each lane combines with lane ``l ^ d``.
+    Both partners compute ``op(a, b)`` / ``op(b, a)``, so for a commutative
+    ``op`` every lane of a group ends with the same, deterministic value."""
+    assert g & (g - 1) == 0, f"group {g} is not a power of two"
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    d = 1
+    while d < g:
+        partner = jnp.where((lane & d) == 0, _lane_roll(x, -d, kernel),
+                            _lane_roll(x, d, kernel))
+        x = op(x, partner)
+        d *= 2
+    return x
+
+
+def _compact_groups(x, g, shift_bits, kernel):
+    """(R, B) integer-valued f32 -> (R, B // g) f32: each aligned group of
+    ``g`` lanes summed into one, lane j of a group weighted by
+    ``2 ** (shift_bits * j)``.  Exact for the byte-sized sums the codecs
+    form (bf16 holds every operand, f32 every partial sum)."""
+    r, b = x.shape
+    if kernel:
+        row = jax.lax.broadcasted_iota(jnp.int32, (b, b // g), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (b, b // g), 1)
+        weight = (1 << (shift_bits * (row % g))).astype(jnp.float32)
+        sel = jnp.where(row // g == col, weight, 0.0)
+        return jnp.dot(x.astype(jnp.bfloat16), sel.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+    weight = jnp.asarray([2.0 ** (shift_bits * j) for j in range(g)],
+                         jnp.float32)
+    return jnp.sum(x.reshape(r, b // g, g) * weight, axis=-1)
+
+
+def _to_u8(byte_values):
+    """Exact byte values in [0, 255] (f32) -> uint8."""
+    return byte_values.astype(jnp.int32).astype(jnp.uint8)
+
+
+def _pack_fields(q, code_max, pack, kernel):
     """(R, B) float codes in [-code_max, code_max] -> (R, B // pack) uint8.
 
     Codes are biased to the unsigned field ``code + code_max + 1`` (always
@@ -128,118 +201,62 @@ def _pack_fields(q, code_max, pack):
     codes are 0 -> field mid-range; the bias is purely a fixed offset) and
     ``pack`` consecutive fields are shifted into one byte, low code first.
     """
-    r, b = q.shape
-    field = (q + _lit(float(code_max + 1), q)).astype(jnp.uint32)
-    f3 = field.reshape(r, b // pack, pack)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, pack), 2)
-    shifts = _match_vma(shifts * jnp.uint32(8 // pack), f3)
-    out = jnp.sum(f3 << shifts, axis=-1)
-    out = _match_vma(out, f3)            # reductions strip vma
-    return out.astype(jnp.uint8)
+    return _to_u8(_compact_groups(q + float(code_max + 1), pack, 8 // pack,
+                                  kernel))
 
 
 def _unpack_fields(code_bytes, code_max, pack):
     """(R, B // pack) uint8 -> (R, B) f32 codes (inverse of _pack_fields)."""
-    r, w = code_bytes.shape
     width = 8 // pack
-    b3 = code_bytes.astype(jnp.uint32).reshape(r, w, 1)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, pack), 2)
-    shifts = _match_vma(shifts * jnp.uint32(width), b3)
-    fields = (b3 >> shifts) & jnp.uint32((1 << width) - 1)
-    codes = fields.reshape(r, w * pack).astype(jnp.float32)
-    return codes - _lit(float(code_max + 1), codes)
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1, code_bytes.shape[1] * pack), 1)
+    fields = ((_expand_groups(_bytes_to_i32(code_bytes), pack)
+               >> (width * (lane % pack))) & ((1 << width) - 1))
+    return fields.astype(jnp.float32) - float(code_max + 1)
 
 
 def _scale_to_bf16_bytes(scale_col):
     """(R, 1) f32 (bf16-exact) -> (R, 2) uint8, least-significant byte first
-    (same byte order discipline as the int8 path's fp32 scale image)."""
-    u16 = jax.lax.bitcast_convert_type(scale_col.astype(jnp.bfloat16),
-                                       jnp.uint16)
-    u = u16.astype(jnp.uint32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, SUB_SCALE_BYTES), 1)
-    shifts = _match_vma(shifts * jnp.uint32(8), u)
-    return ((u >> shifts) & jnp.uint32(0xFF)).astype(jnp.uint8)
+    (same byte order discipline as the int8 path's fp32 scale image).  A
+    bf16-exact f32 carries the bf16 image in its high 16 bits."""
+    u = jax.lax.bitcast_convert_type(scale_col, jnp.int32)
+    shifts = 16 + jax.lax.broadcasted_iota(
+        jnp.int32, (1, SUB_SCALE_BYTES), 1) * 8
+    return ((u >> shifts) & 0xFF).astype(jnp.uint8)
 
 
 def _bf16_bytes_to_scale(scale_bytes):
     """(R, 2) uint8 -> (R, 1) f32 (inverse of _scale_to_bf16_bytes)."""
-    b = scale_bytes.astype(jnp.uint32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, SUB_SCALE_BYTES), 1)
-    shifts = _match_vma(shifts * jnp.uint32(8), b)
-    u = jnp.sum(b << shifts, axis=1, keepdims=True)
-    u = _match_vma(u, scale_bytes)       # reductions strip vma
-    bf = jax.lax.bitcast_convert_type(u.astype(jnp.uint16), jnp.bfloat16)
-    return bf.astype(jnp.float32)
+    b = _bytes_to_i32(scale_bytes)
+    u = (b[:, 0:1] << 16) | (b[:, 1:2] << 24)
+    return jax.lax.bitcast_convert_type(u, jnp.float32)
 
 
-def _pack_bits(bits):
+def _pack_bits(bits, kernel):
     """(R, B) {0,1} -> (R, B // 8) uint8, bit j of byte i = element 8i+j."""
-    r, b = bits.shape
-    b3 = bits.astype(jnp.uint32).reshape(r, b // 8, 8)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 8), 2)
-    shifts = _match_vma(shifts, b3)
-    out = jnp.sum(b3 << shifts, axis=-1)
-    out = _match_vma(out, b3)            # reductions strip vma
-    return out.astype(jnp.uint8)
+    return _to_u8(_compact_groups(bits.astype(jnp.float32), 8, 1, kernel))
 
 
 def _unpack_bits(bitmap_bytes):
     """(R, B // 8) uint8 -> (R, B) f32 {0, 1}."""
-    r, w = bitmap_bytes.shape
-    b3 = bitmap_bytes.astype(jnp.uint32).reshape(r, w, 1)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 8), 2)
-    shifts = _match_vma(shifts, b3)
-    bits = (b3 >> shifts) & jnp.uint32(1)
-    return bits.reshape(r, w * 8).astype(jnp.float32)
-
-
-def _topk_select(y, u_sel, k):
-    """Magnitude-proportional one-per-stratum selection.
-
-    Splits each row into k strata of g = B // k contiguous elements and
-    picks exactly one element per stratum via the exponential race
-    ``argmin_i  -log(u_i) / w_i`` with weights ``w_i = |y_i| + eps`` —
-    P(pick i) = w_i / sum_stratum(w) exactly, so the transmitted value
-    ``y_i / p_i = y_i * sum(w) / w_i`` is an unbiased estimate of the
-    stratum (inverse-probability scaling).  Ties in the race keys (only
-    possible through float collisions) break to the lowest index,
-    deterministically and identically on the jnp and Pallas paths.
-
-    Returns (onehot3 (R, k, g) bool, v (R, k) f32 scaled values).
-    """
-    r, b = y.shape
-    g = b // k
-    y3 = y.reshape(r, k, g)
-    w = jnp.abs(y3) + _lit(1e-30, y3)
-    u3 = jnp.maximum(u_sel.reshape(r, k, g), _lit(1e-37, y3))
-    keys = -jnp.log(u3) / w
-    kmin = jnp.min(keys, axis=-1, keepdims=True)
-    kmin = _match_vma(kmin, keys)        # reductions strip vma
-    idx = jax.lax.broadcasted_iota(jnp.int32, (r, k, g), 2)
-    idx = _match_vma(idx, keys)
-    g_fill = _match_vma(jnp.asarray(g, jnp.int32), keys)
-    masked = jnp.where(keys <= kmin, idx, g_fill)
-    sel = jnp.min(masked, axis=-1, keepdims=True)
-    sel = _match_vma(sel, masked)        # reductions strip vma
-    onehot3 = idx == sel
-    wsum = jnp.sum(w, axis=-1, keepdims=True)
-    wsum = _match_vma(wsum, w)           # reductions strip vma
-    v = jnp.sum(jnp.where(onehot3, y3 * (wsum / w), _lit(0.0, y3)), axis=-1)
-    v = _match_vma(v, y3)                # reductions strip vma
-    return onehot3, v
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1, bitmap_bytes.shape[1] * 8), 1)
+    bits = (_expand_groups(_bytes_to_i32(bitmap_bytes), 8) >> (lane % 8)) & 1
+    return bits.astype(jnp.float32)
 
 
 # -- encode / decode cores (one code path for ref AND kernels) --------------
 
-def _subbyte_encode_core(y, noise, step, code_bits):
+def _subbyte_encode_core(y, noise, step, code_bits, kernel=False):
     """(R, B) f32 + (R, B) uniform noise -> (R, B//pack + 2) uint8 rows."""
     cm = subbyte_code_max(code_bits)
     pack = subbyte_pack(code_bits)
     y = y.astype(jnp.float32)
     scale = _row_scale(y, step, cm)
-    q = _sr_clip(y / scale, noise, cm, y)
+    q = _sr_clip(y / scale, noise, cm)
     return jnp.concatenate(
-        [_pack_fields(q, cm, pack), _scale_to_bf16_bytes(scale)], axis=1)
+        [_pack_fields(q, cm, pack, kernel), _scale_to_bf16_bytes(scale)],
+        axis=1)
 
 
 def _subbyte_decode_core(payload, block, code_bits):
@@ -252,17 +269,40 @@ def _subbyte_decode_core(payload, block, code_bits):
     return codes * scale
 
 
-def _topk_encode_core(y, noise, step, k):
+def _topk_encode_core(y, noise, step, k, kernel=False):
     """(R, B) f32 + (R, 2B) noise (cols [0,B) selection, [B, B+k) rounding)
-    -> (R, B//8 + k + 2) uint8 rows: bitmap || int8 values || bf16 scale."""
+    -> (R, B//8 + k + 2) uint8 rows: bitmap || int8 values || bf16 scale.
+
+    Magnitude-proportional one-per-stratum selection: each row splits into
+    k strata of g = B // k contiguous elements, and each stratum transmits
+    exactly ONE element, picked by the exponential race
+    ``argmin_i  -log(u_i) / w_i`` with weights ``w_i = |y_i| + eps`` —
+    P(pick i) = w_i / sum_stratum(w) exactly, so the transmitted value
+    ``y_i / p_i = y_i * sum(w) / w_i`` is an unbiased estimate of the
+    stratum (inverse-probability scaling).  Ties in the race keys (only
+    possible through float collisions) break to the lowest index.  Every
+    stratum reduction runs over lane groups in place (``_group_reduce``):
+    the selected value sits at its lane, zeros elsewhere, until the codes
+    are compacted to k bytes.
+    """
     r, b = y.shape
+    g = b // k
     y = y.astype(jnp.float32)
-    onehot3, v = _topk_select(y, noise[:, :b], k)
+    w = jnp.abs(y) + jnp.float32(1e-30)
+    keys = -jnp.log(jnp.maximum(noise[:, :b], jnp.float32(1e-37))) / w
+    kmin = _group_reduce(keys, g, jnp.minimum, kernel)
+    idx = jax.lax.broadcasted_iota(jnp.int32, (r, b), 1) % g
+    sel = _group_reduce(jnp.where(keys <= kmin, idx, g), g, jnp.minimum,
+                        kernel)
+    onehot = idx == sel
+    wsum = _group_reduce(w, g, jnp.add, kernel)
+    v = jnp.where(onehot, y * (wsum / w), 0.0)
     scale = _row_scale(v, step, 127)
-    q = _sr_clip(v / scale, noise[:, b:b + k], 127, v)
-    vals = jax.lax.bitcast_convert_type(q.astype(jnp.int8), jnp.uint8)
+    q = _sr_clip(v / scale, _expand_groups(noise[:, b:b + k], g), 127)
+    vals = _compact_groups(q, g, 0, kernel).astype(jnp.int8)
     return jnp.concatenate(
-        [_pack_bits(onehot3.reshape(r, b)), vals,
+        [_pack_bits(onehot, kernel),
+         jax.lax.bitcast_convert_type(vals, jnp.uint8),
          _scale_to_bf16_bytes(scale)], axis=1)
 
 
@@ -270,15 +310,11 @@ def _topk_decode_core(payload, block, k):
     """(R, B//8 + k + 2) uint8 payload rows -> (R, B) f32 (dense, zeros at
     unselected positions)."""
     wb = block // 8
-    r = payload.shape[0]
-    g = block // k
     bits = _unpack_bits(payload[:, :wb])
     codes = jax.lax.bitcast_convert_type(
         payload[:, wb:wb + k], jnp.int8).astype(jnp.float32)
     scale = _bf16_bytes_to_scale(payload[:, wb + k:])
-    vals = codes * scale                                     # (R, k)
-    d3 = bits.reshape(r, k, g) * vals.reshape(r, k, 1)
-    return d3.reshape(r, block)
+    return bits * _expand_groups(codes * scale, block // k)
 
 
 def combine_core(d_self, d_l, d_r, xt, m, w_self, w_side, deamp):
@@ -353,15 +389,14 @@ def _encode_pallas(core, width, noise_cols, y, noise, fixed_step,
 
     def kernel(y_ref, noise_ref, step_ref, payload_ref):
         y_t = y_ref[...].astype(jnp.float32)
-        payload_ref[...] = core(y_t, noise_ref[...],
-                                _match_vma(step_ref[0], y_t))
+        payload_ref[...] = core(y_t, noise_ref[...], step_ref[0])
 
     step_arr = jnp.reshape(jnp.asarray(fixed_step, jnp.float32), (1,))
     y, noise, step_arr = _align_vma(y, noise, step_arr)
     vma_kw = _out_vma(y, noise, step_arr)
     return pl.pallas_call(
         kernel, grid=grid,
-        in_specs=[y_spec, noise_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[y_spec, noise_spec, SMEM_SCALARS],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n, width), jnp.uint8, **vma_kw),
         interpret=interpret,
@@ -409,7 +444,7 @@ def _combine_pallas(decode, width, payload_self, payload_left, payload_right,
     w = jnp.stack([jnp.asarray(w_self, jnp.float32),
                    jnp.asarray(w_side, jnp.float32),
                    jnp.asarray(deamp, jnp.float32)])
-    in_specs = [pl.BlockSpec(memory_space=pl.ANY), pay(payload_self),
+    in_specs = [SMEM_SCALARS, pay(payload_self),
                 pay(payload_left), pay(payload_right), row(x_tilde),
                 row(m_agg)]
     (w, payload_self, payload_left, payload_right, x_tilde, m_agg) = \
@@ -431,7 +466,8 @@ def subbyte_encode_pallas(y, noise, code_bits, fixed_step=None,
                           interpret=None, row_offset=0, n_rows=None):
     """(n, B) f32 -> (n, B // pack + 2) uint8 bit-packed payload."""
     return _encode_pallas(
-        lambda yt, nt, st: _subbyte_encode_core(yt, nt, st, code_bits),
+        lambda yt, nt, st: _subbyte_encode_core(yt, nt, st, code_bits,
+                                                kernel=True),
         subbyte_payload_width(y.shape[1], code_bits), y.shape[1],
         y, noise, fixed_step, interpret, row_offset, n_rows)
 
@@ -457,7 +493,7 @@ def topk_encode_pallas(y, noise, k, fixed_step=None, interpret=None,
     """(n, B) f32 + (n, 2B) noise -> (n, B//8 + k + 2) uint8 sparse payload
     (selection bitmap || int8 values || bf16 scale)."""
     return _encode_pallas(
-        lambda yt, nt, st: _topk_encode_core(yt, nt, st, k),
+        lambda yt, nt, st: _topk_encode_core(yt, nt, st, k, kernel=True),
         topk_payload_width(y.shape[1], k), 2 * y.shape[1],
         y, noise, fixed_step, interpret, row_offset, n_rows)
 

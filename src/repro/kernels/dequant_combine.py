@@ -22,9 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .quantize import (BLOCK, SCALE_BYTES, TILE_N, _align_vma,
-                       _bytes_to_scale, _chunk_view, _out_vma,
-                       _row_index_map, default_interpret)
+from .quantize import (BLOCK, SCALE_BYTES, SMEM_SCALARS, TILE_N, _align_vma,
+                       _chunk_view, _out_vma, _row_index_map,
+                       default_interpret)
 
 __all__ = ["dequant_combine_pallas", "dequant_combine_payload_pallas"]
 
@@ -73,7 +73,7 @@ def dequant_combine_pallas(codes_self, scale_self, codes_left, scale_left,
     return pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+        in_specs=[SMEM_SCALARS,
                   row, scal, row, scal, row, scal, row, row],
         out_specs=(row, row, row),
         out_shape=out_shape,
@@ -82,27 +82,33 @@ def dequant_combine_pallas(codes_self, scale_self, codes_left, scale_left,
       scale_right, x_tilde, m_agg)
 
 
-def _decode_payload_tile(p, block):
-    """(TILE_N, block+4) uint8 wire tile -> dequantized (TILE_N, block) f32.
+def _payload_scales(payload, block):
+    """(rows, block+4) uint8 wire rows -> their fp32 scales (rows, 1).
 
-    Codes are a same-width bitcast view; the fp32 scale is reassembled from
-    its byte image in-kernel (no separate scales operand on the wire)."""
-    codes = jax.lax.bitcast_convert_type(p[:, :block], jnp.int8)
-    scale = _bytes_to_scale(p[:, block:])
-    return codes.astype(jnp.float32) * scale
+    Decoded in XLA, outside the kernel: a kernel that reads the four
+    trailer bytes of a (TILE_N, block+4) uint8 tile gets bytes of other
+    rows on a TPU v5e, and the scales are only 4 of each row's block+4
+    bytes."""
+    rows = payload.shape[0]
+    return jax.lax.bitcast_convert_type(
+        payload[:, block:].reshape(rows, 1, SCALE_BYTES), jnp.float32)
 
 
-def _payload_kernel(w_ref, ps_ref, pl_ref, pr_ref, xt_ref, m_ref,
-                    xt_out_ref, m_out_ref, comb_ref):
+def _payload_kernel(w_ref, ps_ref, ss_ref, pl_ref, sl_ref, pr_ref, sr_ref,
+                    xt_ref, m_ref, xt_out_ref, m_out_ref, comb_ref):
+    block = xt_ref.shape[1]
+
+    def decode(p_ref, s_ref):
+        # codes are a same-width bitcast view of the payload's first bytes
+        codes = jax.lax.bitcast_convert_type(p_ref[...][:, :block], jnp.int8)
+        return codes.astype(jnp.float32) * s_ref[...]
+
     w_self = w_ref[0]
     w_side = w_ref[1]
     deamp = w_ref[2]
-    block = xt_ref.shape[1]
-    d_self = _decode_payload_tile(ps_ref[...], block)
-    d_l = _decode_payload_tile(pl_ref[...], block)
-    d_r = _decode_payload_tile(pr_ref[...], block)
-    x_t = xt_ref[...] + deamp * d_self
-    m = m_ref[...] + w_side * deamp * (d_l + d_r)
+    x_t = xt_ref[...] + deamp * decode(ps_ref, ss_ref)
+    m = m_ref[...] + w_side * deamp * (decode(pl_ref, sl_ref)
+                                       + decode(pr_ref, sr_ref))
     xt_out_ref[...] = x_t
     m_out_ref[...] = m
     comb_ref[...] = w_self * x_t + m
@@ -118,9 +124,10 @@ def dequant_combine_payload_pallas(payload_self, payload_left, payload_right,
     """Payload-view receive side: three (n_blocks, BLOCK+4) uint8 wire
     buffers (self / left / right), packed shadows (n_blocks, BLOCK) f32.
 
-    One fused launch decodes all three payloads (scales region decoded
-    in-kernel) and applies the shadow update + ring combine for the whole
-    parameter tree.  Returns (x_tilde', m_agg', combined).
+    One fused launch decodes all three payloads (their fp32 scales read
+    out in XLA first, :func:`_payload_scales`) and applies the shadow
+    update + ring combine for the whole parameter tree.  Returns
+    (x_tilde', m_agg', combined).
 
     Chunk view (the pipelined exchange): static ``row_offset``/``n_rows``
     restrict the launch to one tile-aligned row range.  Operands that are
@@ -148,17 +155,23 @@ def dequant_combine_payload_pallas(payload_self, payload_left, payload_right,
         return pl.BlockSpec((TILE_N, b + SCALE_BYTES),
                             _row_index_map(arr.shape[0], n, tile_off))
 
+    def scal(arr):
+        return pl.BlockSpec((TILE_N, 1),
+                            _row_index_map(arr.shape[0], n, tile_off))
+
     out_row = pl.BlockSpec((TILE_N, b), lambda i: (i, 0))
     w = jnp.stack([jnp.asarray(w_self, jnp.float32),
                    jnp.asarray(w_side, jnp.float32),
                    jnp.asarray(deamp, jnp.float32)])
-    in_specs = [pl.BlockSpec(memory_space=pl.ANY), pay(payload_self),
-                pay(payload_left), pay(payload_right), row(x_tilde),
-                row(m_agg)]
-    (w, payload_self, payload_left, payload_right, x_tilde, m_agg) = \
-        _align_vma(w, payload_self, payload_left, payload_right, x_tilde,
-                   m_agg)
-    vma_kw = _out_vma(w, payload_self, x_tilde)
+    operands = [w]
+    in_specs = [SMEM_SCALARS]
+    for p in (payload_self, payload_left, payload_right):
+        operands += [p, _payload_scales(p, b)]
+        in_specs += [pay(p), scal(p)]
+    operands += [x_tilde, m_agg]
+    in_specs += [row(x_tilde), row(m_agg)]
+    operands = _align_vma(*operands)
+    vma_kw = _out_vma(*operands)
     out_shape = tuple(jax.ShapeDtypeStruct((n, b), jnp.float32, **vma_kw)
                       for _ in range(3))
     return pl.pallas_call(
@@ -168,4 +181,4 @@ def dequant_combine_payload_pallas(payload_self, payload_left, payload_right,
         out_specs=(out_row, out_row, out_row),
         out_shape=out_shape,
         interpret=interpret,
-    )(w, payload_self, payload_left, payload_right, x_tilde, m_agg)
+    )(*operands)

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .quantize import _lit, _match_vma, _out_vma, default_interpret
+from .quantize import SMEM_SCALARS, _out_vma, default_interpret
 
 __all__ = ["gqa_decode_pallas", "TILE_S"]
 
@@ -48,13 +48,12 @@ def _kernel(softcap_arr, q_ref, k_ref, v_ref, valid_ref,
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     cap = softcap_arr[0]
     s = jnp.where(cap > 0.0, cap * jnp.tanh(s / jnp.where(cap > 0.0, cap, 1.0)), s)
-    s = jnp.where(valid, s, _lit(-1e30, s))           # (g_pad, TILE_S)
+    s = jnp.where(valid, s, -1e30)                    # (g_pad, TILE_S)
 
     m_blk = jnp.max(s, axis=-1, keepdims=True)        # (g_pad, 1)
-    m_blk = _match_vma(m_blk, s)
     p = jnp.exp(s - m_blk)
-    p = jnp.where(valid, p, _lit(0.0, p))
-    l_blk = _match_vma(jnp.sum(p, axis=-1, keepdims=True), s)
+    p = jnp.where(valid, p, 0.0)
+    l_blk = jnp.sum(p, axis=-1, keepdims=True)
     acc_blk = jnp.dot(p, v, preferred_element_type=jnp.float32)  # (g_pad, hd)
 
     @pl.when(j == 0)
@@ -114,7 +113,7 @@ def gqa_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),                      # softcap
+            SMEM_SCALARS,                                           # softcap
             pl.BlockSpec((1, g_pad, hd), lambda i, j: (i, 0, 0)),   # q row
             pl.BlockSpec((1, TILE_S, hd), lambda i, j: (i, j, 0)),  # k tile
             pl.BlockSpec((1, TILE_S, hd), lambda i, j: (i, j, 0)),  # v tile

@@ -1,8 +1,10 @@
-"""Jit'd wrappers dispatching between the Pallas kernels and the jnp oracle.
+"""Wrappers that launch the Pallas kernels (``use_pallas``) or compute the
+jnp oracles.
 
 The public API works on arbitrary 1-D (already flattened + padded) parameter
 shards; padding/blocking is handled here so callers (core.distributed) stay
-shape-agnostic.
+shape-agnostic.  On a TPU a ``use_pallas`` call always runs its compiled
+kernel (see :func:`_use_kernel`).
 """
 from __future__ import annotations
 
@@ -15,9 +17,9 @@ import jax.numpy as jnp
 from . import bitpack, ref
 from .dequant_combine import (dequant_combine_pallas,
                               dequant_combine_payload_pallas)
-from .gqa_decode import gqa_decode_pallas
-from .quantize import (BLOCK, SCALE_BYTES, TILE_N, quantize_blocks_pallas,
-                       quantize_payload_pallas)
+from .gqa_decode import TILE_S, gqa_decode_pallas
+from .quantize import (BLOCK, SCALE_BYTES, TILE_N, _vma_of, default_interpret,
+                       quantize_blocks_pallas, quantize_payload_pallas)
 
 __all__ = ["blockify", "unblockify", "quantize_blocks", "dequant_combine",
            "gqa_decode", "BLOCK", "SCALE_BYTES", "padded_block_rows",
@@ -46,24 +48,25 @@ def unblockify(blocks: jax.Array, n: int) -> jax.Array:
     return blocks.reshape(-1)[:n]
 
 
-def _vma_carrying(*arrays) -> bool:
-    """True when any input is vma-varying (i.e. we are inside a shard_map
-    with check_vma=True).  jax 0.8.2's *interpret-mode* pallas executor
-    cannot replay kernel jaxprs on vma-typed values (out buffers and sliced
-    blocks are re-created without vma, so every binop fails type-checking),
-    so the jit'd wrappers fall back to the bit-identical jnp reference there.
-    On a real TPU (interpret=False) kernel avals are vma-stripped by design
-    and the pallas path is used unconditionally.  Pre-vma jax (0.4.x, no
-    ``jax.typeof``) has no such type system: always False."""
-    from .quantize import _vma_of
-    return any(_vma_of(a) for a in arrays)
+def _use_kernel(use_pallas: bool, *arrays) -> bool:
+    """Whether a wrapper launches its Pallas kernel.
+
+    Compiled (on a TPU) the kernel always runs when ``use_pallas`` asks for
+    it.  Off the TPU the kernels run in interpret mode, whose executor
+    cannot replay a kernel jaxpr on vma-typed values (inside
+    ``shard_map(check_vma=True)``); there, and only there, the wrapper
+    computes the bit-identical jnp reference instead."""
+    if not use_pallas:
+        return False
+    return not default_interpret() or not any(_vma_of(a) for a in arrays)
 
 
 def quantize_blocks(y_blocks: jax.Array, noise: jax.Array,
                     fixed_step=None, use_pallas: bool = False):
     """(rows, BLOCK) f32 -> (codes int8, scales f32 (rows,1))."""
-    if use_pallas and not _vma_carrying(y_blocks, noise):
-        return quantize_blocks_pallas(y_blocks, noise, fixed_step=fixed_step)
+    if _use_kernel(use_pallas, y_blocks, noise):
+        return quantize_blocks_pallas(y_blocks, noise, fixed_step=fixed_step,
+                                      interpret=default_interpret())
     return ref.quantize_blocks_ref(y_blocks, noise, fixed_step=fixed_step)
 
 
@@ -109,13 +112,29 @@ def _chunk_rows(a: jax.Array, row_offset: int, n_rows: int | None):
     return jax.lax.slice_in_dim(a, row_offset, row_offset + n_rows, axis=0)
 
 
-def _tile_aligned(n_full: int, row_offset: int, n_rows: int | None) -> bool:
-    """Whether a chunk view is launchable as a Pallas grid (TILE_N-aligned
-    offset and height).  Mixed WirePlans produce row-granular codec runs at
-    leaf boundaries; unaligned runs take the bit-identical jnp reference
-    path instead (ref == pallas is pinned by tests/test_codec.py)."""
-    n = n_full if n_rows is None else n_rows
-    return row_offset % TILE_N == 0 and n % TILE_N == 0 and n > 0
+def _tile_view(row_offset: int, n_rows: int | None, *arrays):
+    """Kernel operands for a chunk view: ``(arrays, kwargs, n)``.
+
+    A TILE_N-aligned view passes through with its static ``row_offset`` /
+    ``n_rows`` (the kernels read it in place via BlockSpec index maps).  An
+    unaligned one — mixed WirePlans cut codec runs at leaf boundaries — is
+    sliced out and zero-padded to whole tiles; rows are independent, so the
+    caller keeps the first ``n`` result rows and they are bit-identical to
+    an in-place launch."""
+    n = arrays[0].shape[0] if n_rows is None else n_rows
+    if row_offset % TILE_N == 0 and n % TILE_N == 0 and n > 0:
+        return arrays, dict(row_offset=row_offset, n_rows=n_rows), n
+    pad = -n % TILE_N
+    arrays = tuple(jnp.pad(_chunk_rows(a, row_offset, n), ((0, pad), (0, 0)))
+                   for a in arrays)
+    return arrays, {}, n
+
+
+def _head(out, n: int):
+    """The first ``n`` rows of a kernel result (or of each of a tuple)."""
+    if isinstance(out, tuple):
+        return tuple(_head(o, n) for o in out)
+    return out if out.shape[0] == n else out[:n]
 
 
 def _noise_lead(noise: jax.Array, cols: int) -> jax.Array:
@@ -139,10 +158,12 @@ def quantize_payload(y_blocks: jax.Array, noise: jax.Array,
     full-height operands (the pipelined exchange unit): the Pallas path
     reads the chunk in-kernel via BlockSpec index offsets, the jnp path
     takes a static slice; both emit only the chunk's payload rows."""
-    if use_pallas and not _vma_carrying(y_blocks, noise) \
-            and _tile_aligned(y_blocks.shape[0], row_offset, n_rows):
-        return quantize_payload_pallas(y_blocks, noise, fixed_step=fixed_step,
-                                       row_offset=row_offset, n_rows=n_rows)
+    if _use_kernel(use_pallas, y_blocks, noise):
+        (y_blocks, noise), view, n = _tile_view(row_offset, n_rows,
+                                                y_blocks, noise)
+        return _head(quantize_payload_pallas(
+            y_blocks, noise, fixed_step=fixed_step,
+            interpret=default_interpret(), **view), n)
     codes, scales = ref.quantize_blocks_ref(
         _chunk_rows(y_blocks, row_offset, n_rows),
         _chunk_rows(_noise_lead(noise, y_blocks.shape[1]), row_offset,
@@ -161,11 +182,12 @@ def subbyte_encode_payload(y_blocks: jax.Array, noise: jax.Array,
     """Bit-packed sub-byte quantize-to-wire: (rows, BLOCK) f32 ->
     (rows, BLOCK // (8 // code_bits) + 2) uint8 (packed codes || bf16
     scale).  Same chunk-view contract as :func:`quantize_payload`."""
-    if use_pallas and not _vma_carrying(y_blocks, noise) \
-            and _tile_aligned(y_blocks.shape[0], row_offset, n_rows):
-        return bitpack.subbyte_encode_pallas(
+    if _use_kernel(use_pallas, y_blocks, noise):
+        (y_blocks, noise), view, n = _tile_view(row_offset, n_rows,
+                                                y_blocks, noise)
+        return _head(bitpack.subbyte_encode_pallas(
             y_blocks, noise, code_bits, fixed_step=fixed_step,
-            row_offset=row_offset, n_rows=n_rows)
+            interpret=default_interpret(), **view), n)
     return bitpack.subbyte_encode_ref(
         _chunk_rows(y_blocks, row_offset, n_rows),
         _chunk_rows(_noise_lead(noise, y_blocks.shape[1]), row_offset,
@@ -200,12 +222,12 @@ def subbyte_decode_combine(payload_self, payload_left, payload_right,
                            row_offset: int = 0, n_rows: int | None = None):
     """Sub-byte receive side (unpack + shadow update + combine fused);
     same chunk-view contract as :func:`dequant_combine_payload`."""
-    if use_pallas and not _vma_carrying(payload_self, x_tilde, m_agg) \
-            and _tile_aligned(x_tilde.shape[0], row_offset, n_rows):
-        return bitpack.subbyte_combine_pallas(
-            payload_self, payload_left, payload_right, x_tilde, m_agg,
-            w_self, w_side, deamp, code_bits, row_offset=row_offset,
-            n_rows=n_rows)
+    if _use_kernel(use_pallas, payload_self, x_tilde, m_agg):
+        ops, view, n = _tile_view(row_offset, n_rows, x_tilde, m_agg,
+                                  payload_self, payload_left, payload_right)
+        return _head(bitpack.subbyte_combine_pallas(
+            *ops[2:], *ops[:2], w_self, w_side, deamp, code_bits,
+            interpret=default_interpret(), **view), n)
     return _decode_combine_ref(
         lambda p, b: bitpack.subbyte_decode_ref(p, b, code_bits),
         (payload_self, payload_left, payload_right), x_tilde, m_agg,
@@ -220,11 +242,12 @@ def topk_encode_payload(y_blocks: jax.Array, noise: jax.Array, k: int,
     noise -> (rows, BLOCK // 8 + k + 2) uint8 (bitmap || int8 values ||
     bf16 scale).  Noise columns [0, BLOCK) drive the magnitude-proportional
     selection, [BLOCK, BLOCK + k) the value rounding."""
-    if use_pallas and not _vma_carrying(y_blocks, noise) \
-            and _tile_aligned(y_blocks.shape[0], row_offset, n_rows):
-        return bitpack.topk_encode_pallas(
+    if _use_kernel(use_pallas, y_blocks, noise):
+        (y_blocks, noise), view, n = _tile_view(row_offset, n_rows,
+                                                y_blocks, noise)
+        return _head(bitpack.topk_encode_pallas(
             y_blocks, noise, k, fixed_step=fixed_step,
-            row_offset=row_offset, n_rows=n_rows)
+            interpret=default_interpret(), **view), n)
     return bitpack.topk_encode_ref(
         _chunk_rows(y_blocks, row_offset, n_rows),
         _chunk_rows(_noise_lead(noise, 2 * y_blocks.shape[1]), row_offset,
@@ -243,11 +266,12 @@ def topk_decode_combine(payload_self, payload_left, payload_right,
                         n_rows: int | None = None):
     """Top-k receive side (bitmap scatter + shadow update + combine fused);
     same chunk-view contract as :func:`dequant_combine_payload`."""
-    if use_pallas and not _vma_carrying(payload_self, x_tilde, m_agg) \
-            and _tile_aligned(x_tilde.shape[0], row_offset, n_rows):
-        return bitpack.topk_combine_pallas(
-            payload_self, payload_left, payload_right, x_tilde, m_agg,
-            w_self, w_side, deamp, k, row_offset=row_offset, n_rows=n_rows)
+    if _use_kernel(use_pallas, payload_self, x_tilde, m_agg):
+        ops, view, n = _tile_view(row_offset, n_rows, x_tilde, m_agg,
+                                  payload_self, payload_left, payload_right)
+        return _head(bitpack.topk_combine_pallas(
+            *ops[2:], *ops[:2], w_self, w_side, deamp, k,
+            interpret=default_interpret(), **view), n)
     return _decode_combine_ref(
         lambda p, b: bitpack.topk_decode_ref(p, b, k),
         (payload_self, payload_left, payload_right), x_tilde, m_agg,
@@ -257,21 +281,28 @@ def topk_decode_combine(payload_self, payload_left, payload_right,
 def gqa_decode(q, k, v, valid, softcap=None, use_pallas: bool = False):
     """Flash-decode partials (m, l, acc) over a KV-cache shard.
 
-    q: (b, kvh, g, hd); k/v: (b, S, kvh, hd); valid: (S,).  S must be a
-    multiple of TILE_S for the pallas path; the ref path is shape-free."""
-    if use_pallas and not _vma_carrying(q, k, v) \
-            and k.shape[1] % 512 == 0:
-        return gqa_decode_pallas(q, k, v, valid, softcap=softcap)
+    q: (b, kvh, g, hd); k/v: (b, S, kvh, hd); valid: (S,).  The Pallas path
+    pads S to a TILE_S multiple with invalid positions, which add exactly
+    nothing to the partials."""
+    if _use_kernel(use_pallas, q, k, v):
+        pad = -k.shape[1] % TILE_S
+        if pad:
+            k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                    for a in (k, v))
+            valid = jnp.pad(valid, (0, pad))
+        return gqa_decode_pallas(q, k, v, valid, softcap=softcap,
+                                 interpret=default_interpret())
     return ref.gqa_decode_ref(q, k, v, valid, softcap=softcap)
 
 
 def dequant_combine(codes_self, scale_self, codes_left, scale_left,
                     codes_right, scale_right, x_tilde, m_agg,
                     w_self, w_side, deamp, use_pallas: bool = False):
-    if use_pallas and not _vma_carrying(codes_self, x_tilde, m_agg):
+    if _use_kernel(use_pallas, codes_self, x_tilde, m_agg):
         return dequant_combine_pallas(
             codes_self, scale_self, codes_left, scale_left, codes_right,
-            scale_right, x_tilde, m_agg, w_self, w_side, deamp)
+            scale_right, x_tilde, m_agg, w_self, w_side, deamp,
+            interpret=default_interpret())
     return ref.dequant_combine_ref(
         codes_self, scale_self, codes_left, scale_left, codes_right,
         scale_right, x_tilde, m_agg, w_self, w_side, deamp)
@@ -291,11 +322,12 @@ def dequant_combine_payload(payload_self, payload_left, payload_right,
     resync-rebuilt m_agg slice) are used as-is, full-height persistent
     shadows are viewed at the chunk offset; all three results come back
     chunk-height."""
-    if use_pallas and not _vma_carrying(payload_self, x_tilde, m_agg) \
-            and _tile_aligned(x_tilde.shape[0], row_offset, n_rows):
-        return dequant_combine_payload_pallas(
-            payload_self, payload_left, payload_right, x_tilde, m_agg,
-            w_self, w_side, deamp, row_offset=row_offset, n_rows=n_rows)
+    if _use_kernel(use_pallas, payload_self, x_tilde, m_agg):
+        ops, view, n = _tile_view(row_offset, n_rows, x_tilde, m_agg,
+                                  payload_self, payload_left, payload_right)
+        return _head(dequant_combine_payload_pallas(
+            *ops[2:], *ops[:2], w_self, w_side, deamp,
+            interpret=default_interpret(), **view), n)
     block = x_tilde.shape[1]
     cs, ss = unpack_payload(_chunk_rows(payload_self, row_offset, n_rows),
                             block)

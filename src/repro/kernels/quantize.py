@@ -25,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["quantize_blocks_pallas", "quantize_payload_pallas", "TILE_N",
            "BLOCK", "SCALE_BYTES", "default_interpret"]
@@ -32,6 +33,10 @@ __all__ = ["quantize_blocks_pallas", "quantize_payload_pallas", "TILE_N",
 TILE_N = 32     # rows per grid step (int8 sublane tile)
 BLOCK = 512     # quantization block = lane-dim multiple of 128
 SCALE_BYTES = 4  # one fp32 scale per row, appended to the wire payload
+#: whole-array SMEM operand: the kernels' scalar inputs (grid step,
+#: combine weights) — read as ``ref[i]``, which Mosaic allows only on
+#: SMEM/VMEM refs
+SMEM_SCALARS = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def default_interpret() -> bool:
@@ -66,97 +71,56 @@ def _row_index_map(arr_rows: int, n: int, tile_off: int):
 
 
 def _vma_of(x) -> frozenset:
-    """vma of a value's aval, across jax versions: pre-vma jax (no
-    ``jax.typeof`` / ``jax.lax.pcast``, e.g. 0.4.x) has no varying/invariant
-    type distinction at all — everything reports the empty set and every
-    vma lift below becomes a no-op."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return getattr(typeof(x), "vma", frozenset()) or frozenset()
+    """The mesh axes ``x`` is typed as varying over (empty outside
+    ``shard_map(check_vma=True)``)."""
+    return jax.typeof(x).vma
 
 
-def _pcast_varying(x, axes):
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None or not axes:
-        return x
-    return pcast(x, tuple(axes), to="varying")
-
-
-def _match_vma(x, like):
-    """Lift x (pvary) to the vma of `like`.
-
-    jax 0.8.2 pallas interpret-mode kernels traced under
-    shard_map(check_vma=True) keep vma on elementwise ops but STRIP it on
-    reductions, and never auto-insert pvary on literals — so any binop mixing
-    those fails vma type-checking.  Explicit lifting is a no-op on real-TPU
-    lowering (kernel avals carry no vma there) and on pre-vma jax."""
-    missing = tuple(_vma_of(like) - _vma_of(x))
-    return _pcast_varying(x, missing)
-
-
-def _lit(v, like):
-    return _match_vma(jnp.asarray(v, jnp.float32), like)
-
-
-def _stochastic_round_clip(s, noise, like):
+def _stochastic_round_clip(s, noise):
     lo = jnp.floor(s)
     frac = s - lo
     q = lo + (noise < frac).astype(jnp.float32)
-    return jnp.clip(q, _lit(-127.0, like), _lit(127.0, like))
+    return jnp.clip(q, -127.0, 127.0)
 
 
 def _adaptive_kernel(y_ref, noise_ref, codes_ref, scales_ref):
     y = y_ref[...].astype(jnp.float32)                     # (TILE_N, BLOCK)
     noise = noise_ref[...]
     absmax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)   # (TILE_N, 1)
-    absmax = _match_vma(absmax, y)       # reductions strip vma (see above)
-    scale = jnp.maximum(absmax, _lit(1e-30, y)) * _lit(1.0 / 127.0, y)
+    scale = jnp.maximum(absmax, 1e-30) * jnp.float32(1.0 / 127.0)
     s = y / scale
-    codes_ref[...] = _stochastic_round_clip(s, noise, y).astype(jnp.int8)
+    codes_ref[...] = _stochastic_round_clip(s, noise).astype(jnp.int8)
     scales_ref[...] = scale
 
 
 def _fixed_kernel(y_ref, noise_ref, step_ref, codes_ref, scales_ref):
     y = y_ref[...].astype(jnp.float32)
     noise = noise_ref[...]
-    step = _match_vma(step_ref[0], y)                      # scalar grid-step
-    scale = jnp.broadcast_to(step, (y.shape[0], 1))
+    scale = jnp.broadcast_to(step_ref[0], (y.shape[0], 1))   # SMEM scalar
     s = y / scale
-    codes_ref[...] = _stochastic_round_clip(s, noise, y).astype(jnp.int8)
+    codes_ref[...] = _stochastic_round_clip(s, noise).astype(jnp.int8)
     scales_ref[...] = scale
 
 
 def _scale_to_bytes(scale_col):
     """(T, 1) f32 -> (T, SCALE_BYTES) uint8, least-significant byte first.
 
-    Same-width bitcast + byte extraction only (shape-changing bitcasts are
-    not portable inside kernels); matches XLA's f32->uint8 bitcast order
-    used by ``ops.pack_payload`` (pinned by ``test_payload_byte_order``).
-    """
-    u = jax.lax.bitcast_convert_type(scale_col, jnp.uint32)        # (T, 1)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, SCALE_BYTES), 1)
-    shifts = _match_vma(shifts * jnp.uint32(8), u)
-    return ((u >> shifts) & jnp.uint32(0xFF)).astype(jnp.uint8)    # (T, 4)
-
-
-def _bytes_to_scale(scale_bytes):
-    """(T, SCALE_BYTES) uint8 -> (T, 1) f32 (inverse of _scale_to_bytes)."""
-    b = scale_bytes.astype(jnp.uint32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, SCALE_BYTES), 1)
-    shifts = _match_vma(shifts * jnp.uint32(8), b)
-    u = jnp.sum(b << shifts, axis=1, keepdims=True)                # (T, 1)
-    u = _match_vma(u, scale_bytes)       # reductions strip vma (see above)
-    return jax.lax.bitcast_convert_type(u, jnp.float32)
+    Same-width bitcast + signed-int byte extraction only (shape-changing
+    bitcasts are not portable inside kernels, and Mosaic has no unsigned
+    reductions or float->uint casts); matches XLA's f32->uint8 bitcast
+    order used by ``ops.pack_payload`` (pinned by
+    ``test_payload_byte_order``)."""
+    u = jax.lax.bitcast_convert_type(scale_col, jnp.int32)         # (T, 1)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, SCALE_BYTES), 1) * 8
+    return ((u >> shifts) & 0xFF).astype(jnp.uint8)                # (T, 4)
 
 
 def _payload_adaptive_kernel(y_ref, noise_ref, payload_ref):
     y = y_ref[...].astype(jnp.float32)                     # (TILE_N, BLOCK)
     noise = noise_ref[...]
     absmax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
-    absmax = _match_vma(absmax, y)
-    scale = jnp.maximum(absmax, _lit(1e-30, y)) * _lit(1.0 / 127.0, y)
-    q = _stochastic_round_clip(y / scale, noise, y)
+    scale = jnp.maximum(absmax, 1e-30) * jnp.float32(1.0 / 127.0)
+    q = _stochastic_round_clip(y / scale, noise)
     payload_ref[:, : y.shape[1]] = jax.lax.bitcast_convert_type(
         q.astype(jnp.int8), jnp.uint8)
     payload_ref[:, y.shape[1]:] = _scale_to_bytes(scale)
@@ -165,9 +129,8 @@ def _payload_adaptive_kernel(y_ref, noise_ref, payload_ref):
 def _payload_fixed_kernel(y_ref, noise_ref, step_ref, payload_ref):
     y = y_ref[...].astype(jnp.float32)
     noise = noise_ref[...]
-    step = _match_vma(step_ref[0], y)
-    scale = jnp.broadcast_to(step, (y.shape[0], 1))
-    q = _stochastic_round_clip(y / scale, noise, y)
+    scale = jnp.broadcast_to(step_ref[0], (y.shape[0], 1))   # SMEM scalar
+    q = _stochastic_round_clip(y / scale, noise)
     payload_ref[:, : y.shape[1]] = jax.lax.bitcast_convert_type(
         q.astype(jnp.int8), jnp.uint8)
     payload_ref[:, y.shape[1]:] = _scale_to_bytes(scale)
@@ -175,8 +138,7 @@ def _payload_fixed_kernel(y_ref, noise_ref, step_ref, payload_ref):
 
 def _out_vma(*args):
     """vma kwarg for pallas out ShapeDtypeStructs: union of the input vmas
-    (required under shard_map check_vma=True; empty dict elsewhere,
-    including on pre-vma jax versions)."""
+    (required under shard_map check_vma=True; empty dict elsewhere)."""
     vma: frozenset = frozenset()
     for a in args:
         vma |= _vma_of(a)
@@ -185,14 +147,14 @@ def _out_vma(*args):
 
 def _align_vma(*args):
     """pcast every array to the union vma of the group (no-op outside
-    shard_map and on pre-vma jax) so the pallas kernel sees uniformly-typed
-    inputs."""
+    shard_map) so the pallas kernel sees uniformly-typed inputs."""
     union: frozenset = frozenset()
     for a in args:
         union |= _vma_of(a)
     if not union:
         return args
-    return tuple(_pcast_varying(a, tuple(union - _vma_of(a))) for a in args)
+    return tuple(jax.lax.pcast(a, tuple(union - _vma_of(a)), to="varying")
+                 for a in args)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -234,7 +196,7 @@ def quantize_blocks_pallas(y: jax.Array, noise: jax.Array,
         _fixed_kernel,
         grid=grid,
         in_specs=[row_spec, row_spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  SMEM_SCALARS],
         out_specs=(row_spec, scale_spec),
         out_shape=out_shape,
         interpret=interpret,
@@ -291,7 +253,7 @@ def quantize_payload_pallas(y: jax.Array, noise: jax.Array,
     return pl.pallas_call(
         _payload_fixed_kernel,
         grid=grid,
-        in_specs=[y_spec, noise_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[y_spec, noise_spec, SMEM_SCALARS],
         out_specs=payload_spec,
         out_shape=jax.ShapeDtypeStruct((n, b + SCALE_BYTES), jnp.uint8,
                                        **vma_kw),

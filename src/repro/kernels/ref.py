@@ -1,8 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose references).
 
-These are also the production fallback path on backends without Pallas
-support (this CPU container runs them everywhere except the interpret-mode
-kernel tests).
+They are also what ``kernels/ops.py`` computes when ``use_pallas`` is off,
+and, in interpret mode only, inside ``shard_map(check_vma=True)``.  On a
+TPU a ``use_pallas`` call always runs its compiled kernel.
 """
 from __future__ import annotations
 

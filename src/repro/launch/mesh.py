@@ -24,14 +24,8 @@ __all__ = ["make_production_mesh", "make_cpu_mesh"]
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across versions: ``axis_types``/``AxisType`` only exist
-    on newer jax; older versions (0.4.x) take just (shape, axes) and treat
-    every axis as the equivalent of Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

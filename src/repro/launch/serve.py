@@ -28,8 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.models.params import ParamDef
-from repro.models.sharding import (ParallelContext, make_context,
-                                   shard_map_compat)
+from repro.models.sharding import ParallelContext, make_context
 
 __all__ = ["ServeSetup", "build_serve_setup", "build_prefill_setup",
            "cache_partition_specs"]
@@ -190,8 +189,8 @@ def build_serve_setup(
         return {"params": state["params"], "cache": new_cache,
                 "tokens": next_ids}
 
-    step_sm = shard_map_compat(step_body, mesh, in_specs=(state_spec,),
-                               out_specs=state_spec, check=False)
+    step_sm = jax.shard_map(step_body, mesh=mesh, in_specs=(state_spec,),
+                            out_specs=state_spec, check_vma=False)
     serve_step = jax.jit(step_sm, donate_argnums=(0,))
 
     return ServeSetup(
@@ -244,9 +243,9 @@ def build_prefill_setup(cfg: ModelConfig, mesh: jax.sharding.Mesh, *,
         return next_ids, cache
 
     tok_out_spec = P(b_spec, None)
-    step_sm = shard_map_compat(
-        step_body, mesh, in_specs=(p_specs, batch_spec),
-        out_specs=(tok_out_spec, cache_spec), check=False)
+    step_sm = jax.shard_map(
+        step_body, mesh=mesh, in_specs=(p_specs, batch_spec),
+        out_specs=(tok_out_spec, cache_spec), check_vma=False)
     prefill_step = jax.jit(step_sm)
     return PrefillSetup(
         cfg=cfg, ctx=ctx, defs=defs, mesh=mesh, prefill_step=prefill_step,

@@ -33,8 +33,7 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.models.params import (ParamDef, local_block_shape,
                                  storage_partition_spec, storage_shape_dtype)
-from repro.models.sharding import (ParallelContext, make_context,
-                                   shard_map_compat)
+from repro.models.sharding import ParallelContext, make_context
 from repro.optim import by_name as opt_by_name
 from repro.optim.schedules import (constant_schedule, cosine_warmup_schedule,
                                    inverse_power_schedule)
@@ -74,10 +73,21 @@ def batch_partition_spec(ctx: ParallelContext, global_batch: int,
     return P(*([None] * (extra_dims + 1)))
 
 
+def _model_axes(ctx: ParallelContext) -> tuple[str, ...]:
+    """The model axis, where it shards something.  The train-state specs
+    never name a size-1 ``model`` axis: named, it would type every leaf
+    the exchange unpacks from the packed buffer as varying over it, and the
+    model-replicated leaves could then not leave ``shard_map`` without a
+    collective that moves nothing (cf. ``_invariant_over_model``)."""
+    return (ctx.tp_axis,) if ctx.tp > 1 else ()
+
+
 def _param_specs(defs_tree, ctx: ParallelContext):
     data_axes = _data_axes(ctx)
+    tp_axis = ctx.tp_axis if _model_axes(ctx) else None
     return jax.tree.map(
-        lambda d: storage_partition_spec(d, data_axes=data_axes),
+        lambda d: storage_partition_spec(d, data_axes=data_axes,
+                                         tp_axis=tp_axis),
         defs_tree, is_leaf=lambda x: isinstance(x, ParamDef))
 
 
@@ -89,35 +99,44 @@ def _param_shapes(defs_tree, ctx: ParallelContext):
 
 
 def _mesh_lead_axes(ctx: ParallelContext) -> tuple[str, ...]:
-    """Every mesh axis, pod-major — the leading dim of the packed consensus
-    buffers is sharded over ALL of them (each device owns its own packing
-    of its local parameter shard)."""
-    return (*_data_axes(ctx), "model")
+    """Every mesh axis that shards something, pod-major — the leading dim
+    of the packed consensus buffers is sharded over all of them (each
+    device owns its own packing of its local parameter shard)."""
+    return (*_data_axes(ctx), *_model_axes(ctx))
 
 
-def _sync_replicated_grads(grads, defs: T.ModelDefs, ctx: ParallelContext):
-    """Pre-vma compat: mean model-replicated leaves' grads over the tp axis.
+def _invariant_over_model(x_next, defs: T.ModelDefs, ctx: ParallelContext):
+    """Retype the exchange's model-replicated leaves as invariant over
+    ``model`` (their out_specs do not name it).
 
-    Old ``jax.experimental.shard_map(check_rep=False)`` (jax 0.4.x) has no
-    vma type system, so the AD transpose never inserts the psums that keep
-    per-rank cotangents of replicated compute consistent — model-replicated
-    leaves (``ParamDef.tp_dim is None``: norms, replicated projections)
-    would receive per-rank *different* gradients and the replicas would
-    silently drift apart.  Averaging them over ``model`` restores replica
-    identity (and is exactly the invariant value on symmetric paths).  On
-    vma-typed jax (``jax.shard_map`` exists) the transpose already yields
-    rank-identical grads and this is a no-op.
-    """
-    if hasattr(jax, "shard_map") or ctx.tp == 1:
-        return grads
-
-    def sync(d, g):
-        if d.tp_dim is not None:
-            return g
-        return jax.lax.psum(g, ctx.tp_axis) / ctx.tp
-
-    return jax.tree.map(sync, defs.storage, grads,
-                        is_leaf=lambda x: isinstance(x, ParamDef))
+    The exchange packs every leaf of a device's shard into one buffer that
+    varies over all mesh axes, so each leaf it returns is typed varying
+    over ``model``.  The model-replicated ones (``ParamDef.tp_dim is None``:
+    norms, replicated projections) hold equal values on every model rank,
+    because the quantization noise is shared across ``model``
+    (``core.distributed._device_key``).  One ``pmax`` over ``model`` of
+    their concatenation returns those same values, typed invariant: exact,
+    and the one collective over ``model`` that the exchange adds.  On
+    ``tp == 1`` meshes no spec names ``model`` and nothing is done."""
+    if ctx.tp == 1:
+        return x_next
+    leaves, treedef = jax.tree.flatten(x_next)
+    flat_defs = jax.tree.leaves(defs.storage,
+                                is_leaf=lambda x: isinstance(x, ParamDef))
+    rep = [i for i, (d, x) in enumerate(zip(flat_defs, leaves))
+           if d.tp_dim is None and ctx.tp_axis in jax.typeof(x).vma]
+    if not rep:
+        return x_next
+    flat = jax.lax.pmax(wire.lift_concat(
+        [leaves[i].astype(jnp.float32).reshape(-1) for i in rep]),
+        ctx.tp_axis)
+    start = 0
+    for i in rep:
+        x = leaves[i]
+        leaves[i] = flat[start:start + x.size].reshape(x.shape).astype(
+            x.dtype)
+        start += x.size
+    return jax.tree.unflatten(treedef, leaves)
 
 
 def consensus_wire_layout(defs: T.ModelDefs, ctx: ParallelContext,
@@ -307,7 +326,6 @@ def build_train_setup(
         # shards; normalize to the node-mean objective f_i.
         if ctx.fsdp > 1:
             grads = jax.tree.map(lambda g: g / ctx.fsdp, grads)
-        grads = _sync_replicated_grads(grads, defs, ctx)
         lr_k = sched(k)
         x_half, opt_state = opt.step(state["opt"], state["params"], grads, lr_k)
         # consensus noise stream rooted at the run seed (folded per step;
@@ -320,6 +338,7 @@ def build_train_setup(
         cons_in = jax.tree.map(lambda a: a[0], state["consensus"])
         x_next, cons_state, cmetrics = consensus.exchange(
             state["params"], x_half, cons_in, k, key)
+        x_next = _invariant_over_model(x_next, defs, ctx)
         cons_state = jax.tree.map(
             lambda a: wire.pvary_to(a, _mesh_lead_axes(ctx))[None],
             cons_state)
@@ -352,8 +371,8 @@ def build_train_setup(
                               **{k: P() for k in ccfg.telemetry_metric_keys()},
                               **({"consensus_err": P()} if track_consensus_error else {})})
 
-    step_sm = shard_map_compat(step_body, mesh, in_specs=in_specs,
-                               out_specs=out_specs, check=True)
+    step_sm = jax.shard_map(step_body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=True)
     train_step = jax.jit(step_sm, donate_argnums=(0,))
 
     return TrainSetup(
@@ -383,9 +402,9 @@ def init_consensus_state(setup: TrainSetup, params) -> Any:
         st = setup.consensus.init_state(p)
         return jax.tree.map(lambda a: wire.pvary_to(a, lead)[None], st)
 
-    init_sm = shard_map_compat(pack_local, setup.mesh,
-                               in_specs=(state_spec["params"],),
-                               out_specs=state_spec["consensus"])
+    init_sm = jax.shard_map(pack_local, mesh=setup.mesh,
+                            in_specs=(state_spec["params"],),
+                            out_specs=state_spec["consensus"])
     return jax.jit(init_sm)(params)
 
 
@@ -425,15 +444,16 @@ def build_exchange_probe(setup: TrainSetup):
         key = jax.random.fold_in(jax.random.PRNGKey(0), k)
         cons_in = jax.tree.map(lambda a: a[0], cons_state)
         x_next, cons_out, _ = cons.exchange(params, params, cons_in, k, key)
+        x_next = _invariant_over_model(x_next, setup.defs, ctx)
         cons_out = jax.tree.map(
             lambda a: wire.pvary_to(a, lead)[None], cons_out)
         return x_next, cons_out
 
-    sm = shard_map_compat(
-        body, setup.mesh,
+    sm = jax.shard_map(
+        body, mesh=setup.mesh,
         in_specs=(state_spec["params"], state_spec["consensus"], P()),
         out_specs=(state_spec["params"], state_spec["consensus"]),
-        check=True)
+        check_vma=True)
     return jax.jit(sm)
 
 
@@ -473,6 +493,7 @@ def measure_consensus_overhead(setup: TrainSetup, state,
 def main(argv=None):
     from repro.configs import get_config, reduced
     from repro.data import SyntheticLMDataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_cpu_mesh
 
     ap = argparse.ArgumentParser(description="decentralized LM training")
@@ -602,6 +623,7 @@ def main(argv=None):
     ap.add_argument("--run-id", default=None,
                     help="telemetry run id (default: a wall-clock stamp)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -98,12 +98,12 @@ def local_block_shape(d: ParamDef, tp: int, fsdp: int) -> tuple[int, ...]:
 
 
 def storage_partition_spec(d: ParamDef, data_axes: tuple[str, ...] = ("data",),
-                           tp_axis: str = "model") -> P:
+                           tp_axis: str | None = "model") -> P:
     """PartitionSpec for the storage layout on the production mesh.
 
     ``data_axes`` may be ("data",) or ("pod", "data") — in the multi-pod case
     the consensus node set spans pods, so the fsdp/storage dim is sharded over
-    both axes (pod-major).
+    both axes (pod-major).  ``tp_axis=None`` names no model axis.
     """
     ndim = len(d.shape)
     spec: list[Any] = [None] * ndim

@@ -22,23 +22,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ParallelContext", "local_context", "make_context",
-           "shard_map_compat"]
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(check_vma=...)``; older versions only
-    have ``jax.experimental.shard_map.shard_map(check_rep=...)`` (and no vma
-    type system — ``check`` is dropped to False there, since replication
-    checking without vma rejects the runtime's collectives)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+__all__ = ["ParallelContext", "local_context", "make_context"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,29 +87,17 @@ class ParallelContext:
         psum/tp keeps both the value and the gradient exact."""
         if self.tp == 1 or not self.in_shard_map:
             return x
-        typeof = getattr(jax, "typeof", None)
-        if typeof is None:
-            # pre-vma jax: replicated compute is already a plain replicated
-            # value and grad does NOT insert psums at invariant boundaries
-            # (that pathology is the vma type system's), so the correct
-            # fallback is the identity — psum/tp here would route the
-            # cotangent through psum's old-shard_map transpose and scale
-            # gradients wrongly
-            return x
-        if self.tp_axis in getattr(typeof(x), "vma", frozenset()):
+        if self.tp_axis in jax.typeof(x).vma:
             return jax.lax.psum(x, self.tp_axis) / self.tp
         return x
 
     def pvary_tp(self, x):
         """Mark x as vma-varying over the model axis (no-op semantically;
         needed so lax.scan carries type-check under check_vma=True when the
-        body contains model-axis all_gathers; no-op on pre-vma jax)."""
+        body contains model-axis all_gathers)."""
         if self.tp == 1 or not self.in_shard_map:
             return x
-        pcast = getattr(jax.lax, "pcast", None)
-        if pcast is None:
-            return x
-        return pcast(x, (self.tp_axis,), to="varying")
+        return jax.lax.pcast(x, (self.tp_axis,), to="varying")
 
     def ag_tp(self, x, axis: int, tiled: bool = True):
         """all_gather over the model axis (seq-sharded attention path)."""
@@ -179,21 +151,10 @@ class ParallelContext:
         ``check_vma=True`` out_specs of ``P()`` valid for every mesh shape."""
         if not self.in_shard_map:
             return x
-        typeof = getattr(jax, "typeof", None)
-        if typeof is None:
-            # pre-vma jax can't tell varying from replicated: psum every
-            # axis of size > 1 and divide — exact for varying values (true
-            # mean) AND replicated ones (n*x/n == x)
-            varying = None
-        else:
-            varying = getattr(typeof(x), "vma", frozenset())
+        varying = jax.typeof(x).vma
         denom = 1
         for a in (self.tp_axis, self.data_axis, self.pod_axis):
-            if a is None:
-                continue
-            take = (self.axis_size_of(a) > 1 if varying is None
-                    else a in varying)
-            if take:
+            if a is not None and a in varying:
                 x = jax.lax.psum(x, a)
                 denom *= self.axis_size_of(a)
         return x / denom if denom > 1 else x

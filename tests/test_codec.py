@@ -359,6 +359,23 @@ def test_adaptive_scale_never_clips(name):
         assert np.max(np.abs(dec - np.asarray(y)) / scale) <= 1.0 + 1e-6
 
 
+def test_bf16_round_is_round_to_nearest_even():
+    """The scale rounding is the f32 -> bf16 cast's round-to-nearest-even,
+    ties included, and the same inside a jit as op by op (it is integer
+    arithmetic, which no compiler may compute at higher precision)."""
+    rng = np.random.default_rng(0)
+    bits = rng.integers(0x00800000, 0x7F000000, size=4096,
+                        dtype=np.int64).astype(np.uint32)
+    # exact ties: low half 0x8000, with an even and an odd bf16 mantissa
+    bits[:64] = (bits[:64] & 0xFFFE0000) | 0x8000
+    bits[64:128] = (bits[64:128] & 0xFFFE0000) | 0x18000
+    x = jnp.asarray(bits.view(np.float32))
+    want = np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(bitpack._bf16_round(x)), want)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(bitpack._bf16_round)(x)), want)
+
+
 def test_count_clipped_semantics():
     b = kops.BLOCK
     for name in ALL_CODECS:

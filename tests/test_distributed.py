@@ -7,6 +7,7 @@ process must keep seeing ONE device for the smoke tests).
 Covered invariants:
   * distributed (fsdp x tp) gradients == single-device oracle
   * ADC-DGD / DGD / allreduce all train; ADC tracks allreduce closely
+  * on a batch shared by every node ADC-DGD follows allreduce (one step)
   * consensus error of allreduce == 0, ADC-DGD stays bounded
   * Pallas kernels (interpret) inside the distributed exchange == jnp path
   * model-replicated leaves stay bit-identical across model ranks
@@ -17,26 +18,9 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-#: Pre-vma jax (0.4.x) has no ``jax.shard_map``; the compat shim falls back
-#: to ``jax.experimental.shard_map(check_rep=False)``, whose AD transpose
-#: handles ``psum`` without the vma pbroadcast insertion — cotangents that
-#: cross tensor-parallel collectives come back re-summed over the model
-#: axis, so gradients of tp>1 runs are scaled wrong (losses still match:
-#: the forward pass is unaffected).  Replica *identity* of model-replicated
-#: leaves is restored by ``launch.train._sync_replicated_grads``; exact
-#: gradient *values* through TP collectives are only correct under the vma
-#: type system.  Tests asserting those values skip below this line.
-needs_vma_grads = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="pre-vma jax.experimental.shard_map(check_rep=False) "
-           "mis-transposes psum across the model axis: gradients through "
-           "tensor-parallel collectives are scaled wrong (forward/loss "
-           "unaffected); requires jax.shard_map's vma type system")
 
 
 def run_sub(body: str, timeout: int = 1500) -> dict:
@@ -125,7 +109,6 @@ print("RESULT", json.dumps({{"max_rel_err": max(errs),
 """
 
 
-@needs_vma_grads
 @pytest.mark.parametrize("arch,data,model,nodes,batch", [
     ("smollm-135m", 4, 2, 1, 8),        # head-sharded, fsdp=4
     ("smollm-135m", 1, 8, 1, 2),        # seq-sharded attention (tp=8 > heads)
@@ -140,12 +123,9 @@ def test_distributed_grads_match_oracle(arch, data, model, nodes, batch):
     assert r["max_rel_err"] < 5e-3
 
 
-@needs_vma_grads
 def test_adc_matches_allreduce_and_dgd():
     """The paper's headline claim, live on the LLM trainer: ADC-DGD's loss
-    curve tracks uncompressed DGD and allreduce closely.  (Skipped on
-    pre-vma jax: the data=4 x model=2 mesh trains through mis-transposed
-    TP psums at lr=1.0, so the loss curves are not comparable there.)"""
+    curve tracks uncompressed DGD and allreduce closely."""
     body = """
 cfg = reduced(get_config("smollm-135m"))
 mesh = make_cpu_mesh(data=4, model=2)
@@ -181,6 +161,39 @@ print("RESULT", __import__("json").dumps(out))
     assert diff_adc < 0.2
     # consensus error stays bounded for adc
     assert max(r["adc_dgd"]["cerr"]) < 10.0
+
+
+def test_adc_applies_local_step_once():
+    """On one batch given to every node, every replica sees the same
+    gradients, so ADC-DGD must follow allreduce up to quantization noise:
+    its wire carries x^k - x_tilde, and the local step is added once,
+    after the combine.  (Carrying x^{k+1/2} - x_tilde instead applies the
+    step twice: then ADC-DGD is 0.3 nats ahead at step 1 and matches
+    allreduce at twice the learning rate.)"""
+    body = """
+cfg = reduced(get_config("smollm-135m"))
+mesh = make_cpu_mesh(data=4, model=1)
+one = SyntheticLMDataset(cfg.vocab_size, 64, 8, seed=0).global_batch_arrays(0)
+out = {}
+for alg in ("adc_dgd", "allreduce"):
+    setup = LT.build_train_setup(cfg, mesh, consensus_nodes=4, algorithm=alg,
+                                 optimizer="adam", lr=1e-3, global_batch=32,
+                                 seq_len=64, seed=0)
+    state = LT.init_train_state(setup, 0)
+    b = jax.device_put({k: np.tile(v, (4, 1)) for k, v in one.items()},
+                       setup.batch_sharding)
+    losses = []
+    for step in range(5):
+        state, m = setup.train_step(state, b)
+        losses.append(float(m["loss"]))
+    out[alg] = losses
+print("RESULT", json.dumps(out))
+"""
+    r = run_sub(body)
+    import numpy as np
+    adc, ar = np.asarray(r["adc_dgd"]), np.asarray(r["allreduce"])
+    assert ar[-1] < ar[0] - 1.0, ar             # it trains
+    assert np.max(np.abs(adc - ar)) < 5e-3, (adc, ar)
 
 
 def test_pallas_kernels_in_distributed_exchange():
@@ -248,9 +261,6 @@ def test_timevarying_ring_stride_schedule_trains():
     """DESIGN.md §Topology schedules: ring_strides=(1,2) re-wires the node
     ring every schedule_period steps (lax.switch over static ppermute
     wirings); ADC-DGD must keep training and stay consensus-bounded."""
-    import jax as _jax
-    if not hasattr(_jax, "shard_map"):
-        pytest.skip("requires jax.shard_map (newer jax)")
     body = """
 cfg = reduced(get_config("smollm-135m"))
 mesh = make_cpu_mesh(data=4, model=2)
@@ -295,3 +305,43 @@ print("RESULT", __import__("json").dumps(
     r = run_sub(body, timeout=2400)
     assert r["losses"][-1] < r["losses"][0] + 0.05
     assert r["cerr"] < 10.0
+
+
+@pytest.mark.parametrize("model", [1, 2])
+def test_train_step_collectives_pinned(model):
+    """The packed ADC step's collectives on model=1 and model=2 meshes:
+    exactly 2 ring ppermutes over ``data``; on model > 1 also exactly one
+    pmax over ``model``, of the model-replicated leaves only (it retypes
+    them invariant over ``model``, launch.train._invariant_over_model)."""
+    body = """
+from repro.models.params import local_block_shape
+cfg = reduced(get_config("smollm-135m"))
+mesh = make_cpu_mesh(data=2, model=%d)
+setup = LT.build_train_setup(cfg, mesh, consensus_nodes=2,
+                             algorithm="adc_dgd", global_batch=4)
+ctx = setup.ctx
+n_rep = sum(int(np.prod(local_block_shape(d, ctx.tp, ctx.fsdp)))
+            for d in jax.tree.leaves(setup.defs.storage,
+                                     is_leaf=lambda x: isinstance(x, ParamDef))
+            if d.tp_dim is None)
+batch = {k: jax.ShapeDtypeStruct((4, 32), jnp.int32)
+         for k in ("tokens", "labels")}
+jaxpr = jax.make_jaxpr(setup.train_step)(setup.state_shape, batch)
+
+def eqns(j):
+    for e in getattr(j, "jaxpr", j).eqns:
+        yield e
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    yield from eqns(sub)
+
+ring = [e for e in eqns(jaxpr) if e.primitive.name == "ppermute"
+        and e.params["axis_name"] == ("data",)]
+pmax = [e for e in eqns(jaxpr) if e.primitive.name == "pmax"
+        and tuple(e.invars[0].aval.shape) == (n_rep,)]
+print("RESULT", json.dumps({"ring": len(ring), "pmax": len(pmax)}))
+""" % model
+    r = run_sub(body)
+    assert r["ring"] == 2, r
+    assert r["pmax"] == (1 if model > 1 else 0), r

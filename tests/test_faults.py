@@ -150,11 +150,13 @@ print("RESULT", json.dumps({"same_seed": max_diff(a, b),
 def test_stale_reuse_is_exactly_the_missing_differential():
     """Packet-level semantics of stale-x_tilde reuse, pinned two ways.
 
-    Deterministic: after ONE lossy step, a receiver with full delivery is
+    Deterministic: after a lossy step, a receiver with full delivery is
     bit-identical to the lossless run, and a receiver that missed a
     packet differs by EXACTLY the in-weighted differential that packet
     carried (the sender's shadow advance xt' - xt) — the drop corrupts
-    nothing else.  Monte-Carlo over 16 drop seeds: the mean absolute
+    nothing else.  The step is the second: the wire carries x^k - xt,
+    which is zero at k = 1 (the shadows start at x^1), so the first
+    step's drops lose nothing.  Monte-Carlo over 16 drop seeds: the mean absolute
     deviation matches the first-order prediction ``rate * (w_fwd |d_up|
     + w_bwd |d_dn|)`` — the stale-reuse error scales with the loss rate
     and the differential magnitude ~ Delta_k, with no constant-order
@@ -178,15 +180,17 @@ def packed(x):
         jax.tree.map(lambda a, d=d: a[d], x)), np.float64)
         for d in range(4)])
 
-ref_x, ref_st = trajectory(kw, tree, steps=1)
-dec = np.asarray(ref_st["x_tilde"], np.float64) - packed(tree)
+_, ref_st1 = trajectory(kw, tree, steps=1)
+ref_x, ref_st = trajectory(kw, tree, steps=2)
+dec = (np.asarray(ref_st["x_tilde"], np.float64)
+       - np.asarray(ref_st1["x_tilde"], np.float64))
 px_ref = packed(ref_x)
 exact = {"full": [], "dropped": []}
 seed_means = []
 for seed in range(16):
-    mask = faults.LossModel(rate=RATE, seed=seed).keep_mask_host(4, [1])[0]
+    mask = faults.LossModel(rate=RATE, seed=seed).keep_mask_host(4, [2])[0]
     got_x, _ = trajectory({**kw, "link_loss": RATE, "loss_seed": seed},
-                          tree, steps=1)
+                          tree, steps=2)
     px_got = packed(got_x)
     gaps = []
     for v in range(4):

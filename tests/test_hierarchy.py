@@ -251,7 +251,7 @@ def run_sub(body: str, timeout: int = 1500) -> dict:
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.core import wire
         from repro.core.distributed import ConsensusConfig, ConsensusRuntime
-        from repro.models.sharding import ParallelContext, shard_map_compat
+        from repro.models.sharding import ParallelContext
 
         mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
         ctx = ParallelContext(tp=1, data_size=4, n_nodes=4, in_shard_map=True)
@@ -277,16 +277,16 @@ def run_sub(body: str, timeout: int = 1500) -> dict:
                 for fk in wire.INFLIGHT_KEYS:
                     cons_spec[fk] = P("data", None)
             init = lambda p: jax.tree.map(lambda a: a[None], rt.init_state(p))
-            init_f = jax.jit(shard_map_compat(
-                init, mesh, in_specs=(pspec,), out_specs=cons_spec,
-                check=False))
+            init_f = jax.jit(jax.shard_map(
+                init, mesh=mesh, in_specs=(pspec,), out_specs=cons_spec,
+                check_vma=False))
             def step(xp, xh, s, k):
                 s = jax.tree.map(lambda a: a[0], s)
                 xn, s2, m = rt.exchange(xp, xh, s, k, jax.random.PRNGKey(7))
                 return xn, jax.tree.map(lambda a: a[None], s2)
-            step_f = jax.jit(shard_map_compat(
-                step, mesh, in_specs=(pspec, pspec, cons_spec, P()),
-                out_specs=(pspec, cons_spec), check=False))
+            step_f = jax.jit(jax.shard_map(
+                step, mesh=mesh, in_specs=(pspec, pspec, cons_spec, P()),
+                out_specs=(pspec, cons_spec), check_vma=False))
             return init_f, step_f
 
         def trajectory(cfg_kw, tree, steps=5):
@@ -300,9 +300,9 @@ def run_sub(body: str, timeout: int = 1500) -> dict:
                     xn, s2, m = rt.exchange(xp, xh, s, k,
                                             jax.random.PRNGKey(7))
                     return xn, s2
-                step_f = jax.jit(shard_map_compat(
-                    step, mesh, in_specs=(pspec, pspec, P(), P()),
-                    out_specs=(pspec, P()), check=False))
+                step_f = jax.jit(jax.shard_map(
+                    step, mesh=mesh, in_specs=(pspec, pspec, P(), P()),
+                    out_specs=(pspec, P()), check_vma=False))
                 st = 0.0
             x = tree
             for k in range(1, steps + 1):

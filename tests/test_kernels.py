@@ -8,19 +8,13 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.dequant_combine import dequant_combine_pallas
-from repro.kernels.quantize import BLOCK, TILE_N, quantize_blocks_pallas
+from repro.kernels.quantize import (BLOCK, TILE_N, _scale_to_bytes,
+                                    quantize_blocks_pallas)
 
 SHAPES = [(32, 128), (32, 512), (64, 512), (96, 256), (320, 128)]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
-# The interpret-mode Pallas path needs the newer jax API (jax.typeof etc.);
-# on older jax only the jnp reference-oracle tests run.
-needs_pallas = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="pallas interpret path requires jax.typeof (newer jax)")
 
-
-@needs_pallas
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("mode", ["adaptive", "fixed"])
@@ -35,7 +29,6 @@ def test_quantize_matches_oracle(shape, dtype, mode):
     np.testing.assert_allclose(np.asarray(s_p), np.asarray(s_r), rtol=1e-6)
 
 
-@needs_pallas
 @pytest.mark.parametrize("shape", SHAPES[:3])
 def test_dequant_combine_matches_oracle(shape):
     key = jax.random.PRNGKey(0)
@@ -86,17 +79,18 @@ def test_quantize_payload_matches_quantize_then_pack():
 
 
 def test_payload_byte_order():
-    """Pin the scale-byte order: the shift-based in-kernel decode must agree
-    with XLA's bitcast (least-significant byte first) — the contract that
-    keeps the Pallas payload kernels bit-identical to the jnp oracle."""
+    """Pin the scale-byte order: the encode kernels' shift-based byte image
+    (``_scale_to_bytes``) is XLA's bitcast (least-significant byte first),
+    which ``ops.unpack_payload`` and the combine kernels decode — the
+    contract that keeps the Pallas payload kernels bit-identical to the jnp
+    oracle."""
     scales = jnp.asarray([[1.5], [-2.25], [3e-7], [1e30]], jnp.float32)
     codes = jnp.zeros((4, BLOCK), jnp.int8)
     payload = ops.pack_payload(codes, scales)
-    sb = payload[:, BLOCK:].astype(jnp.uint32)
-    shifts = (jnp.arange(4, dtype=jnp.uint32) * 8)[None, :]
-    u = jnp.sum(sb << shifts, axis=1, keepdims=True)
-    decoded = jax.lax.bitcast_convert_type(u, jnp.float32)
-    np.testing.assert_array_equal(np.asarray(decoded), np.asarray(scales))
+    np.testing.assert_array_equal(np.asarray(_scale_to_bytes(scales)),
+                                  np.asarray(payload[:, BLOCK:]))
+    np.testing.assert_array_equal(np.asarray(ops.unpack_payload(payload)[1]),
+                                  np.asarray(scales))
 
 
 def test_dequant_combine_payload_matches_unpacked():
@@ -117,7 +111,6 @@ def test_dequant_combine_payload_matches_unpacked():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@needs_pallas
 @pytest.mark.parametrize("shape", SHAPES[:3])
 @pytest.mark.parametrize("mode", ["adaptive", "fixed"])
 def test_quantize_payload_pallas_matches_oracle(shape, mode):
@@ -133,10 +126,9 @@ def test_quantize_payload_pallas_matches_oracle(shape, mode):
     np.testing.assert_array_equal(np.asarray(pl_k), np.asarray(pl_r))
 
 
-@needs_pallas
 @pytest.mark.parametrize("shape", SHAPES[:3])
 def test_dequant_combine_payload_pallas_matches_oracle(shape):
-    """In-kernel scale decode: byte payload in, bit-exact combine out."""
+    """Byte payloads in (scales read out before the kernel), combine out."""
     from repro.kernels.dequant_combine import dequant_combine_payload_pallas
     key = jax.random.PRNGKey(9)
     ks = jax.random.split(key, 6)
@@ -231,7 +223,6 @@ def test_gqa_decode_shard_combine():
 # gqa_decode Pallas kernel (interpret) vs jnp oracle
 # ---------------------------------------------------------------------------
 
-@needs_pallas
 @pytest.mark.parametrize("b,kvh,g,hd,S,cap", [
     (2, 2, 4, 128, 1024, None),      # GQA, 2 S-tiles
     (1, 4, 1, 64, 512, 30.0),        # MHA-ish + softcap, single tile
@@ -259,7 +250,6 @@ def test_gqa_decode_pallas_matches_oracle(b, kvh, g, hd, S, cap, dtype):
     np.testing.assert_allclose(lse_p, lse_r, atol=5e-5 if dtype == jnp.float32 else 5e-2)
 
 
-@needs_pallas
 def test_gqa_decode_pallas_all_masked_tile():
     """Tiles that are fully masked (beyond the causal frontier) must not
     poison the running accumulator."""

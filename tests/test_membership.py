@@ -372,16 +372,16 @@ def build_metrics(rt, tree, keys):
         for fk in wire.INFLIGHT_KEYS:
             cons_spec[fk] = P("data", None)
     init = lambda p: jax.tree.map(lambda a: a[None], rt.init_state(p))
-    init_f = jax.jit(shard_map_compat(
-        init, mesh, in_specs=(pspec,), out_specs=cons_spec, check=False))
+    init_f = jax.jit(jax.shard_map(
+        init, mesh=mesh, in_specs=(pspec,), out_specs=cons_spec, check_vma=False))
     def step(xp, xh, s, k):
         s = jax.tree.map(lambda a: a[0], s)
         xn, s2, m = rt.exchange(xp, xh, s, k, jax.random.PRNGKey(7))
         got = jnp.stack([m[k2] for k2 in keys])
         return xn, jax.tree.map(lambda a: a[None], s2), got[None]
-    step_f = jax.jit(shard_map_compat(
-        step, mesh, in_specs=(pspec, pspec, cons_spec, P()),
-        out_specs=(pspec, cons_spec, P("data")), check=False))
+    step_f = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(pspec, pspec, cons_spec, P()),
+        out_specs=(pspec, cons_spec, P("data")), check_vma=False))
     return init_f, step_f
 
 tree = make_tree(jax.random.PRNGKey(0))

@@ -139,3 +139,30 @@ def test_checkpoint_rejects_mismatched_template(tmp_path):
         load_checkpoint(str(tmp_path), {"w": np.ones((3, 3), np.float32)})
     with pytest.raises(ValueError):
         load_checkpoint(str(tmp_path), {"w": np.ones((2, 2)), "extra": np.ones(1)})
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_set):
+    """The persistent compile cache lives in JAX_COMPILATION_CACHE_DIR when
+    it is set, else in the fixed, git-ignored ``.jax_cache`` of the
+    checkout."""
+    from repro.launch.compile_cache import (DEFAULT_CACHE_DIR,
+                                            enable_compile_cache)
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env_set:
+        assert path == str(tmp_path)
+    else:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache") \
+            == str(DEFAULT_CACHE_DIR)
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()

@@ -274,7 +274,7 @@ def run_sub(body: str, timeout: int = 1500) -> dict:
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.core import wire
         from repro.core.distributed import ConsensusConfig, ConsensusRuntime
-        from repro.models.sharding import ParallelContext, shard_map_compat
+        from repro.models.sharding import ParallelContext
 
         mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
         ctx = ParallelContext(tp=1, data_size=4, n_nodes=4, in_shard_map=True)
@@ -322,18 +322,18 @@ def run_sub(body: str, timeout: int = 1500) -> dict:
                 for fk in wire.INFLIGHT_KEYS:
                     cons_spec[fk] = P("data", None)
             init = lambda p: jax.tree.map(lambda a: a[None], rt.init_state(p))
-            init_f = jax.jit(shard_map_compat(
-                init, mesh, in_specs=(pspec,), out_specs=cons_spec,
-                check=False))
+            init_f = jax.jit(jax.shard_map(
+                init, mesh=mesh, in_specs=(pspec,), out_specs=cons_spec,
+                check_vma=False))
             def step(xp, xh, s, k):
                 s = jax.tree.map(lambda a: a[0], s)
                 xn, s2, m = rt.exchange(xp, xh, s, k, jax.random.PRNGKey(7),
                                         noise=shared_noise(rt, xh, k))
                 return xn, jax.tree.map(lambda a: a[None], s2)
-            step_f = jax.jit(shard_map_compat(
-                step, mesh,
+            step_f = jax.jit(jax.shard_map(
+                step, mesh=mesh,
                 in_specs=(pspec, pspec, cons_spec, P()),
-                out_specs=(pspec, cons_spec), check=False))
+                out_specs=(pspec, cons_spec), check_vma=False))
             return init_f, step_f
 
         def trajectory(cfg_kw, tree, steps=5):
@@ -347,9 +347,9 @@ def run_sub(body: str, timeout: int = 1500) -> dict:
                                             jax.random.PRNGKey(7),
                                             noise=shared_noise(rt, xh, k))
                     return xn, s2
-                step_f = jax.jit(shard_map_compat(
-                    step, mesh, in_specs=(pspec, pspec, P(), P()),
-                    out_specs=(pspec, P()), check=False))
+                step_f = jax.jit(jax.shard_map(
+                    step, mesh=mesh, in_specs=(pspec, pspec, P(), P()),
+                    out_specs=(pspec, P()), check_vma=False))
                 st = 0.0
             x = tree
             for k in range(1, steps + 1):
@@ -466,17 +466,17 @@ def build_m(rt, tree):
     cons_spec = {"x_tilde": P("data", None, None),
                  "m_agg": P("data", None, None)}
     init = lambda p: jax.tree.map(lambda a: a[None], rt.init_state(p))
-    init_f = jax.jit(shard_map_compat(
-        init, mesh, in_specs=(pspec,), out_specs=cons_spec, check=False))
+    init_f = jax.jit(jax.shard_map(
+        init, mesh=mesh, in_specs=(pspec,), out_specs=cons_spec, check_vma=False))
     def step(xp, xh, s, k):
         s = jax.tree.map(lambda a: a[0], s)
         xn, s2, m = rt.exchange(xp, xh, s, k, jax.random.PRNGKey(7),
                                 noise=shared_noise(rt, xh, k))
         return (xn, jax.tree.map(lambda a: a[None], s2),
                 m["overflow_frac"][None])
-    step_f = jax.jit(shard_map_compat(
-        step, mesh, in_specs=(pspec, pspec, cons_spec, P()),
-        out_specs=(pspec, cons_spec, P("data")), check=False))
+    step_f = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(pspec, pspec, cons_spec, P()),
+        out_specs=(pspec, cons_spec, P("data")), check_vma=False))
     return init_f, step_f
 
 def trajectory_m(cfg_kw, tree, steps=5):
@@ -972,9 +972,9 @@ def jaxpr_and_keys(cfg_kw):
         xn, s2, m = rt.exchange(xp, xh, s, k, jax.random.PRNGKey(7))
         keys_box["keys"] = sorted(m.keys())
         return xn, jax.tree.map(lambda a: a[None], s2)
-    probe_f = shard_map_compat(
-        probe, mesh, in_specs=(pspec, pspec, cons_spec, P()),
-        out_specs=(pspec, cons_spec), check=False)
+    probe_f = jax.shard_map(
+        probe, mesh=mesh, in_specs=(pspec, pspec, cons_spec, P()),
+        out_specs=(pspec, cons_spec), check_vma=False)
     jaxpr = jax.make_jaxpr(probe_f)(tree, tree, st,
                                     jnp.asarray(2, jnp.int32))
     return jaxpr, keys_box["keys"]
