@@ -72,6 +72,7 @@ dispatches between stride-specialized exchange traces with ``lax.switch``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -547,8 +548,23 @@ def _ppermute_ring(x, ctx: ParallelContext, shift: int, mask=None,
     if ctx.total_consensus_nodes // group <= 1:
         return x
     axes = _ring_axes(ctx)
-    return jax.lax.ppermute(x, axes if len(axes) > 1 else axes[0],
-                            _flat_ring_perm_masked(ctx, shift, mask, group))
+    with jax.named_scope("permute"):
+        return jax.lax.ppermute(x, axes if len(axes) > 1 else axes[0],
+                                _flat_ring_perm_masked(ctx, shift, mask,
+                                                       group))
+
+
+@contextlib.contextmanager
+def _unit_scope(stage: str, c: int, n_units: int):
+    """The named scope of one transfer unit's ``stage`` (``encode`` or
+    ``combine``, under the exchange's ``exchange`` scope); each chunk of
+    the pipelined transport gets its own (``exchange/encode/chunk1``)."""
+    with jax.named_scope(stage):
+        if n_units == 1:
+            yield
+        else:
+            with jax.named_scope(f"chunk{c}"):
+                yield
 
 
 def _pipeline_schedule(n_units: int, launch, retire, inspect=None) -> list:
@@ -860,6 +876,9 @@ class ConsensusRuntime:
                  noise: Any = None):
         """x_prev: params at step k; x_half: after the local optimizer step.
 
+        Its ops run under the named scope ``exchange``, with ``noise``,
+        ``encode``, ``permute`` and ``combine`` inside it (DESIGN.md §13).
+
         ``noise``: optional pre-generated uniform noise buffer of shape
         ``(layout.n_rows, BLOCK)`` consumed row-for-row by the quantizer.
         When ``None`` (production) each wire path generates its own stream:
@@ -870,6 +889,10 @@ class ConsensusRuntime:
 
         Returns (x_next, new_state, metrics).
         """
+        with jax.named_scope("exchange"):
+            return self._exchange(x_prev, x_half, state, step, key, noise)
+
+    def _exchange(self, x_prev, x_half, state, step, key, noise):
         alg = self.cfg.algorithm
         ctx = self.ctx
         layout = self.state_layout(x_half)
@@ -1194,9 +1217,10 @@ class ConsensusRuntime:
             # ONE noise buffer sized for the plan's widest codec (top-k
             # consumes a second BLOCK-wide region for its selection race);
             # each run's kernels read their leading columns in place
-            noise = jax.random.uniform(
-                key, (layout.n_rows, plan.noise_cols(layout.block)),
-                jnp.float32)
+            with jax.named_scope("noise"):
+                noise = jax.random.uniform(
+                    key, (layout.n_rows, plan.noise_cols(layout.block)),
+                    jnp.float32)
 
         def launch(c):
             """Encode unit c straight out of the full differential (one
@@ -1204,15 +1228,14 @@ class ConsensusRuntime:
             in place), flatten to the unit's 1-D wire buffer and put it on
             both ring directions: 2 collectives per unit regardless of how
             many codec runs the unit carries."""
-            telemetry.trace_mark("quantize", c, rows=units[c].n_rows)
-            pay = plan.encode_unit(units[c], y, noise, fixed_step=step_k,
-                                   use_pallas=cfg.use_pallas)
+            with _unit_scope("encode", c, len(units)):
+                pay = plan.encode_unit(units[c], y, noise, fixed_step=step_k,
+                                       use_pallas=cfg.use_pallas)
             if push and c == last_unit:
                 # the push-sum weight rides the LAST unit's payload as a
                 # 4-byte fp32 trailer — no extra collective; fragment byte
                 # offsets address the payload from 0 and never see it
                 pay = wire.lift_concat([pay, trailer])
-            telemetry.trace_mark("launch", c, rows=units[c].n_rows)
             return (pay, self._ring(pay, +stride, mask=mask),
                     self._ring(pay, -stride, mask=mask))
 
@@ -1224,10 +1247,8 @@ class ConsensusRuntime:
             unit c's in-flight payloads (persistent shadows viewed at each
             fragment's row offset; unit-level epoch-boundary m_agg
             resync)."""
-            telemetry.trace_mark("retire", c)
             pay, p_l, p_r = inflight
             unit = units[c]
-            telemetry.trace_mark("dequant_combine", c, rows=unit.n_rows)
             if push and c == last_unit:
                 recv_w["l"] = jax.lax.bitcast_convert_type(
                     p_l[-wireplan.PUSH_SUM_TRAILER_BYTES:],
@@ -1266,32 +1287,33 @@ class ConsensusRuntime:
                     lambda u=unit: jax.lax.slice_in_dim(
                         mb, u.row_start, u.row_end))
             outs = []
-            for f in unit.fragments:
-                cd = wire_codec.by_name(f.codec)
-                if directed:
-                    # the asymmetric correction term needs the two dense
-                    # neighbor differentials (post loss-zeroing)
-                    dense["l"].append(cd.decode_payload(
+            with _unit_scope("combine", c, len(units)):
+                for f in unit.fragments:
+                    cd = wire_codec.by_name(f.codec)
+                    if directed:
+                        # the asymmetric correction term needs the two
+                        # dense neighbor differentials (post loss-zeroing)
+                        dense["l"].append(cd.decode_payload(
+                            plan.fragment_payload(p_l, f, unit.byte_start),
+                            layout.block))
+                        dense["r"].append(cd.decode_payload(
+                            plan.fragment_payload(p_r, f, unit.byte_start),
+                            layout.block))
+                    if mb_u is None:
+                        m_in = mb                   # full-height in-kernel view
+                    else:
+                        m_in = jax.lax.slice_in_dim(
+                            mb_u, f.row_start - unit.row_start,
+                            f.row_end - unit.row_start)
+                    outs.append(cd.decode_combine(
+                        plan.fragment_payload(pay, f, unit.byte_start),
                         plan.fragment_payload(p_l, f, unit.byte_start),
-                        layout.block))
-                    dense["r"].append(cd.decode_payload(
                         plan.fragment_payload(p_r, f, unit.byte_start),
-                        layout.block))
-                if mb_u is None:
-                    m_in = mb                       # full-height in-kernel view
-                else:
-                    m_in = jax.lax.slice_in_dim(
-                        mb_u, f.row_start - unit.row_start,
-                        f.row_end - unit.row_start)
-                outs.append(cd.decode_combine(
-                    plan.fragment_payload(pay, f, unit.byte_start),
-                    plan.fragment_payload(p_l, f, unit.byte_start),
-                    plan.fragment_payload(p_r, f, unit.byte_start),
-                    xt, m_in, cfg.self_weight, cfg.side_weight,
-                    jnp.float32(1.0), use_pallas=cfg.use_pallas,
-                    row_offset=f.row_start, n_rows=f.n_rows))
-            return tuple(
-                wire.lift_concat([o[i] for o in outs]) for i in range(3))
+                        xt, m_in, cfg.self_weight, cfg.side_weight,
+                        jnp.float32(1.0), use_pallas=cfg.use_pallas,
+                        row_offset=f.row_start, n_rows=f.n_rows))
+                return tuple(
+                    wire.lift_concat([o[i] for o in outs]) for i in range(3))
 
         clipped = [jnp.zeros((), jnp.float32)]
 
@@ -1532,29 +1554,28 @@ class ConsensusRuntime:
             p_r = jnp.where(eff_dn, p_r, jnp.zeros_like(p_r))
 
         # ---- RETIRE: drain the step-(k-1) payloads into the shadows -----
-        telemetry.trace_mark("retire", 0, mode="async")
-        telemetry.trace_mark("dequant_combine", 0, rows=unit.n_rows)
         dense = {"l": [], "r": []} if directed else None
         outs = []
-        for f in unit.fragments:
-            cd = wire_codec.by_name(f.codec)
-            if directed:
-                dense["l"].append(cd.decode_payload(
+        with jax.named_scope("combine"):
+            for f in unit.fragments:
+                cd = wire_codec.by_name(f.codec)
+                if directed:
+                    dense["l"].append(cd.decode_payload(
+                        plan.fragment_payload(p_l, f, unit.byte_start),
+                        layout.block))
+                    dense["r"].append(cd.decode_payload(
+                        plan.fragment_payload(p_r, f, unit.byte_start),
+                        layout.block))
+                outs.append(cd.decode_combine(
+                    plan.fragment_payload(pay, f, unit.byte_start),
                     plan.fragment_payload(p_l, f, unit.byte_start),
-                    layout.block))
-                dense["r"].append(cd.decode_payload(
                     plan.fragment_payload(p_r, f, unit.byte_start),
-                    layout.block))
-            outs.append(cd.decode_combine(
-                plan.fragment_payload(pay, f, unit.byte_start),
-                plan.fragment_payload(p_l, f, unit.byte_start),
-                plan.fragment_payload(p_r, f, unit.byte_start),
-                xt, mb, cfg.self_weight, cfg.side_weight,
-                jnp.float32(1.0), use_pallas=cfg.use_pallas,
-                row_offset=f.row_start, n_rows=f.n_rows))
-        xt_new = wire.lift_concat([o[0] for o in outs])
-        m_new = wire.lift_concat([o[1] for o in outs])
-        comb = wire.lift_concat([o[2] for o in outs])
+                    xt, mb, cfg.self_weight, cfg.side_weight,
+                    jnp.float32(1.0), use_pallas=cfg.use_pallas,
+                    row_offset=f.row_start, n_rows=f.n_rows))
+            xt_new = wire.lift_concat([o[0] for o in outs])
+            m_new = wire.lift_concat([o[1] for o in outs])
+            comb = wire.lift_concat([o[2] for o in outs])
         if directed:
             d_l = wire.lift_concat(dense["l"])
             d_r = wire.lift_concat(dense["r"])
@@ -1616,9 +1637,6 @@ class ConsensusRuntime:
                 lambda nx, p: jnp.where(act_b, nx, p), x_next, x_prev)
 
         # ---- LAUNCH: encode step k against the drained shadow -----------
-        telemetry.trace_mark("quantize", 0, rows=unit.n_rows, mode="async")
-        telemetry.trace_mark("launch", 0, rows=unit.n_rows,
-                             buffers=wire.INFLIGHT_KEYS)
         step_k = self._step_k(step)
         xh_p = layout.pack(x_half)
         if push:
@@ -1627,11 +1645,13 @@ class ConsensusRuntime:
                 ps_new.astype(jnp.float32), jnp.uint8).reshape(-1)
         y = xh_p - xt_new
         if noise is None:
-            noise = jax.random.uniform(
-                key, (layout.n_rows, plan.noise_cols(layout.block)),
-                jnp.float32)
-        new_pay = plan.encode_unit(unit, y, noise, fixed_step=step_k,
-                                   use_pallas=cfg.use_pallas)
+            with jax.named_scope("noise"):
+                noise = jax.random.uniform(
+                    key, (layout.n_rows, plan.noise_cols(layout.block)),
+                    jnp.float32)
+        with jax.named_scope("encode"):
+            new_pay = plan.encode_unit(unit, y, noise, fixed_step=step_k,
+                                       use_pallas=cfg.use_pallas)
         if push:
             new_pay = wire.lift_concat([new_pay, trailer])
         if act_b is not None:
@@ -1766,12 +1786,14 @@ class ConsensusRuntime:
             yb = xp_b - xtb
             residual_sq = residual_sq + jnp.sum(yb * yb)
             if noise is None:       # historical per-leaf noise stream
-                noise_b = jax.random.uniform(leaf_keys[i], yb.shape,
-                                             jnp.float32)
+                with jax.named_scope("noise"):
+                    noise_b = jax.random.uniform(leaf_keys[i], yb.shape,
+                                                 jnp.float32)
             else:                   # injected shared stream (equivalence)
                 noise_b = rowpad(layout.leaf_rows(noise, i), full)
-            codes, scales = kops.quantize_blocks(
-                yb, noise_b, fixed_step=step_k, use_pallas=cfg.use_pallas)
+            with jax.named_scope("encode"):
+                codes, scales = kops.quantize_blocks(
+                    yb, noise_b, fixed_step=step_k, use_pallas=cfg.use_pallas)
             if cfg.quant_mode == "fixed":
                 clipped_acc = clipped_acc + jnp.sum(
                     (jnp.abs(codes.astype(jnp.float32)) >= 127)
@@ -1801,10 +1823,11 @@ class ConsensusRuntime:
                         built = jnp.where(resync_ok, built, mb)
                     return built
                 mb = jax.lax.cond(resync, _rebuild, lambda mb=mb: mb)
-            xt_new_b, m_new_b, comb_b = kops.dequant_combine(
-                codes, scales, c_l, s_l, c_r, s_r, xtb, mb,
-                cfg.self_weight, cfg.side_weight, jnp.float32(1.0),
-                use_pallas=cfg.use_pallas)
+            with jax.named_scope("combine"):
+                xt_new_b, m_new_b, comb_b = kops.dequant_combine(
+                    codes, scales, c_l, s_l, c_r, s_r, xtb, mb,
+                    cfg.self_weight, cfg.side_weight, jnp.float32(1.0),
+                    use_pallas=cfg.use_pallas)
             if directed:
                 # same antisymmetric out-of-kernel correction as the
                 # packed path (see _adc_exchange)

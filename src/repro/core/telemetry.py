@@ -1,6 +1,6 @@
 """Structured telemetry for the consensus stack (schema ``telemetry/v1``).
 
-Three pieces, all zero-cost when unused (DESIGN.md §Observability):
+Two pieces, both zero-cost when unused (DESIGN.md §Observability):
 
 * **Typed per-step counters/gauges.**  The jitted step already returns a
   metrics dict; ``ConsensusConfig(telemetry=True)`` adds the extra
@@ -15,16 +15,9 @@ Three pieces, all zero-cost when unused (DESIGN.md §Observability):
   epoch transitions, resync outcomes — are appended to the same sink as
   ``kind="event"`` records.
 
-* **Span recorder.**  :class:`SpanRecorder` captures the *structural*
-  exchange schedule at trace time (the launch/retire emission order of
-  ``core.distributed._pipeline_schedule`` and the async retire→launch
-  split, via :func:`trace_mark`) and renders it over the measured
-  per-step wall-clock windows as Chrome/Perfetto ``trace_event`` JSON.
-  Spans are schedule-accurate and duration-approximate: XLA does not
-  expose per-collective timestamps on the host mesh, so phase spans
-  subdivide the measured exchange window uniformly — what the timeline
-  shows faithfully is the *overlap structure* (which transfers are in
-  flight while which compute runs), which is the DESIGN §10 claim.
+Where the time goes is not recorded here: the program carries named
+scopes (``embed``, ``attention``, ``exchange/encode``, ...) that the JAX
+profiler's device trace shows (``launch/train.py --profile-dir``).
 
 The wire-byte arithmetic that used to live in three places
 (``ConsensusRuntime.wire_bytes_per_step``, the ``wire_bytes_delivered``
@@ -36,18 +29,16 @@ model on every transport.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import math
 import os
 import time
-from typing import Any, Callable, Iterable
+from typing import Any
 
 __all__ = [
-    "SCHEMA", "EVENT_KINDS", "SPAN_PHASES", "STEP_METRICS",
+    "SCHEMA", "EVENT_KINDS", "STEP_METRICS",
     "WireAccounting", "timing_gate", "validate_record", "Telemetry",
-    "SpanRecorder", "trace_mark", "set_trace_observer",
 ]
 
 SCHEMA = "telemetry/v1"
@@ -55,11 +46,6 @@ SCHEMA = "telemetry/v1"
 #: host-event record names (``kind="event"``, field ``event``)
 EVENT_KINDS = ("codec_decision", "plan_retier", "membership_epoch",
                "resync", "wire_plan", "kernel_fallback", "run_end")
-
-#: exchange span taxonomy (DESIGN.md §Observability): the five phases of
-#: one transfer unit's life on the wire
-SPAN_PHASES = ("quantize", "launch", "in_flight", "retire",
-               "dequant_combine")
 
 #: the typed registry of known per-step metrics: "counter" values are
 #: non-negative per-step totals (bytes, event counts), "gauge" values are
@@ -284,22 +270,18 @@ class Telemetry:
     """Typed counter/gauge registry + schema-versioned JSONL sink.
 
     Writes ``{out_dir}/telemetry-{run_id}.jsonl`` (one record per line,
-    ``meta`` first) and — when ``spans=True`` — a Chrome/Perfetto trace
-    at ``{out_dir}/trace-{run_id}.json`` on :meth:`close`.
+    ``meta`` first).
     """
 
     def __init__(self, run_id: str, out_dir: str = "obs",
-                 config: dict | None = None, git_sha: str | None = None,
-                 spans: bool = False):
+                 config: dict | None = None, git_sha: str | None = None):
         self.run_id = run_id
         self.out_dir = out_dir
         os.makedirs(out_dir, exist_ok=True)
         self.path = os.path.join(out_dir, f"telemetry-{run_id}.jsonl")
-        self.trace_path = os.path.join(out_dir, f"trace-{run_id}.json")
         self._types = dict(STEP_METRICS)
         self._extra_types: dict[str, str] = {}
         self._f = open(self.path, "w")
-        self.spans = SpanRecorder().install() if spans else None
         self._write({"schema": SCHEMA, "kind": "meta", "run_id": run_id,
                      "git_sha": git_sha, "config": dict(config or {}),
                      "time_unix": time.time()})
@@ -357,223 +339,9 @@ class Telemetry:
             return
         self._f.flush()
         self._f.close()
-        if self.spans is not None:
-            self.spans.uninstall()
-            self.spans.save(self.trace_path)
 
     def __enter__(self) -> "Telemetry":
         return self
 
     def __exit__(self, *exc) -> None:
         self.close()
-
-
-# ---------------------------------------------------------------------------
-# Trace-time structural observer
-# ---------------------------------------------------------------------------
-
-_trace_observer: Callable | None = None
-
-
-def set_trace_observer(obs: Callable | None) -> None:
-    """Install (or clear) the module-global schedule observer consumed by
-    :func:`trace_mark`.  Marks fire at TRACE time only — they never
-    enter the jaxpr, so installing an observer cannot change the step
-    trace (the telemetry-off bit-identity pin relies on this)."""
-    global _trace_observer
-    _trace_observer = obs
-
-
-def trace_mark(phase: str, unit: int = 0, **info) -> None:
-    """Record one structural exchange event (called from the exchange
-    closures in core.distributed while they are being traced).  A no-op
-    unless a :class:`SpanRecorder` is installed."""
-    if _trace_observer is not None:
-        _trace_observer(phase, unit, info)
-
-
-# ---------------------------------------------------------------------------
-# Span recorder + Perfetto export
-# ---------------------------------------------------------------------------
-
-#: Perfetto track ids (tid) — one per concern so overlapping spans render
-#: on parallel tracks instead of nesting
-TRACKS = {"compute": 0, "codec": 1, "wire": 2, "inflight": 3, "host": 4}
-_TRACK_NAMES = {0: "model compute (fwd/bwd)", 1: "codec (quantize/dequant)",
-                2: "wire (launch/retire)", 3: "wire in-flight",
-                4: "host"}
-#: which track each exchange phase renders on
-_PHASE_TRACK = {"quantize": "codec", "launch": "wire", "retire": "wire",
-                "dequant_combine": "codec"}
-
-
-class SpanRecorder:
-    """Trace-structure capture + wall-clock span timeline.
-
-    Two span sources:
-
-    * :meth:`span` — a plain wall-clock context manager for host-visible
-      work (whole steps, controller decisions, probes).
-    * :meth:`record_step_window` — renders the captured exchange
-      schedule (``trace_mark`` order) into a measured step window:
-      compute first, then the exchange phases subdividing the tail
-      ``exchange_frac`` of the step.  A launch with no later retire of
-      the same unit in the window (the async transport) leaves its
-      in-flight span OPEN; the next window's first retire closes it —
-      which is exactly how the one-step-stale payload's flight time
-      comes to cover the next step's whole compute span.
-    """
-
-    def __init__(self):
-        self._origin = time.perf_counter()
-        self._events: list[dict] = []
-        self._schedule: list[tuple[str, int, dict]] = []
-        self._seen: set = set()
-        self._pending: list[dict] = []   # open in-flight spans (async)
-
-    # -- trace-structure capture ----------------------------------------
-    def install(self) -> "SpanRecorder":
-        set_trace_observer(self._observe)
-        return self
-
-    def uninstall(self) -> None:
-        set_trace_observer(None)
-
-    def _observe(self, phase: str, unit: int, info: dict) -> None:
-        key = (phase, unit)
-        if key not in self._seen:     # lax.switch traces branches twice
-            self._seen.add(key)
-            self._schedule.append((phase, unit, dict(info)))
-
-    @property
-    def schedule(self) -> list:
-        return list(self._schedule)
-
-    # -- host spans ------------------------------------------------------
-    def us(self, t_perf: float) -> float:
-        return (t_perf - self._origin) * 1e6
-
-    def _emit(self, name: str, ts_us: float, dur_us: float, track: str,
-              args: dict | None = None, cat: str = "exchange") -> None:
-        self._events.append({
-            "name": name, "cat": cat, "ph": "X", "pid": 0,
-            "tid": TRACKS[track], "ts": round(ts_us, 3),
-            "dur": round(max(dur_us, 0.001), 3),
-            **({"args": args} if args else {})})
-
-    @contextlib.contextmanager
-    def span(self, name: str, track: str = "host", args: dict | None = None):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            self._emit(name, self.us(t0), (t1 - t0) * 1e6, track,
-                       args, cat="host")
-
-    # -- schedule-derived exchange spans ---------------------------------
-    def record_step_window(self, step: int, t_start: float, dur_s: float,
-                           exchange_frac: float = 0.25) -> None:
-        """Render step ``step``'s timeline from its measured window.
-
-        ``t_start`` is the host ``time.perf_counter()`` at step launch,
-        ``dur_s`` the blocked wall-clock duration, ``exchange_frac`` the
-        measured (or estimated) fraction the fused exchange takes.
-        """
-        t0 = self.us(t_start)
-        dur = dur_s * 1e6
-        frac = min(max(exchange_frac, 0.02), 0.9)
-        marks = self._schedule
-        compute_end = t0 + dur * (1.0 - frac) if marks else t0 + dur
-        self._emit(f"fwd/bwd step {step}", t0, compute_end - t0,
-                   "compute", cat="compute")
-        if not marks:
-            return
-        win0, win1 = compute_end, t0 + dur
-        slot = (win1 - win0) / len(marks)
-        # the first retire slot closes any in-flight span carried over
-        # from the previous step (the async one-step-stale payload)
-        retire_at = next((win0 + i * slot for i, (ph, _, _)
-                          in enumerate(marks) if ph == "retire"), None)
-        if retire_at is not None:
-            for p in self._pending:
-                self._emit(p["name"], p["ts"], retire_at - p["ts"],
-                           "inflight", p.get("args"))
-            self._pending = []
-        open_launches: dict[int, tuple[float, dict]] = {}
-        for i, (phase, unit, info) in enumerate(marks):
-            s0 = win0 + i * slot
-            self._emit(f"{phase} u{unit}", s0, slot,
-                       _PHASE_TRACK.get(phase, "host"),
-                       {**info, "step": step} if info else {"step": step})
-            if phase == "launch":
-                open_launches[unit] = (s0 + slot, info)
-            elif phase == "retire" and unit in open_launches:
-                fly0, info0 = open_launches.pop(unit)
-                self._emit(f"in_flight u{unit}", fly0, s0 - fly0,
-                           "inflight", {**info0, "step": step})
-        # launches never retired in this window stay in flight across the
-        # step boundary — one span per async in-flight buffer
-        for unit, (fly0, info) in open_launches.items():
-            buffers = info.get("buffers") or (f"u{unit}",)
-            for b in buffers:
-                self._pending.append(
-                    {"name": f"in_flight {b}", "ts": fly0,
-                     "args": {"step": step, "unit": unit}})
-
-    # -- export ----------------------------------------------------------
-    def to_perfetto(self) -> dict:
-        meta = [{"name": "process_name", "ph": "M", "pid": 0,
-                 "args": {"name": "repro consensus"}}]
-        meta += [{"name": "thread_name", "ph": "M", "pid": 0, "tid": tid,
-                  "args": {"name": label}}
-                 for tid, label in sorted(_TRACK_NAMES.items())]
-        events = list(self._events)
-        for p in self._pending:      # close still-open flights at the end
-            end = max((e["ts"] + e["dur"] for e in events), default=p["ts"])
-            events.append({"name": p["name"], "cat": "exchange", "ph": "X",
-                           "pid": 0, "tid": TRACKS["inflight"],
-                           "ts": round(p["ts"], 3),
-                           "dur": round(max(end - p["ts"], 0.001), 3),
-                           "args": p.get("args") or {}})
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms",
-                "otherData": {"schema": SCHEMA, "spans": "schedule-derived"}}
-
-    def save(self, path: str) -> None:
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "w") as f:
-            json.dump(self.to_perfetto(), f)
-
-
-def trace_phase_coverage(trace: dict) -> dict[str, int]:
-    """Span count per exchange phase in an exported Perfetto trace (the
-    CI smoke asserts >= 1 of each for the traced transport)."""
-    counts = {ph: 0 for ph in SPAN_PHASES}
-    for ev in trace.get("traceEvents", ()):
-        if ev.get("ph") != "X":
-            continue
-        name = ev.get("name", "")
-        for ph in SPAN_PHASES:
-            if name.startswith(ph):
-                counts[ph] += 1
-    return counts
-
-
-def trace_has_overlap(trace: dict) -> bool:
-    """Does any in-flight span overlap compute (model or codec) on the
-    timeline?  True for pipelined (transfer vs quantize/dequant) and
-    async (transfer vs next step's fwd/bwd) exports — the DESIGN §10
-    visibility claim."""
-    compute_tids = {TRACKS["compute"], TRACKS["codec"]}
-    fly, work = [], []
-    for ev in trace.get("traceEvents", ()):
-        if ev.get("ph") != "X":
-            continue
-        iv = (ev["ts"], ev["ts"] + ev["dur"])
-        if ev.get("tid") == TRACKS["inflight"]:
-            fly.append(iv)
-        elif ev.get("tid") in compute_tids:
-            work.append(iv)
-    eps = 1e-6
-    return any(f0 < w1 - eps and w0 < f1 - eps
-               for f0, f1 in fly for w0, w1 in work)

@@ -77,8 +77,14 @@ class SyntheticLMDataset:
         return out
 
     def global_batch_arrays(self, step: int) -> dict[str, np.ndarray]:
-        shards = [self.batch(step, s) for s in range(self.n_shards)]
-        return {k: np.concatenate([sh[k] for sh in shards]) for k in shards[0]}
+        """Every shard's rows of ``step``, built under the host span
+        ``input.build`` of the profiler's trace (a flag check when no
+        profiler runs)."""
+        from jax.profiler import TraceAnnotation
+        with TraceAnnotation("input.build"):
+            shards = [self.batch(step, s) for s in range(self.n_shards)]
+            return {k: np.concatenate([sh[k] for sh in shards])
+                    for k in shards[0]}
 
 
 def make_batch_specs(vocab_size: int, seq_len: int, global_batch: int):

@@ -356,10 +356,11 @@ def topk_decode_ref(payload, block, k):
 # Pallas kernels (same cores, tiled TILE_N rows per grid step)
 # ---------------------------------------------------------------------------
 
-def _encode_pallas(core, width, noise_cols, y, noise, fixed_step,
+def _encode_pallas(name, core, width, noise_cols, y, noise, fixed_step,
                    interpret, row_offset, n_rows):
     """Shared encode launch: grid over TILE_N-row tiles of a (chunk view
-    of a) full-height (n, B) operand pair, emitting (n, width) uint8."""
+    of a) full-height (n, B) operand pair, emitting (n, width) uint8;
+    ``name`` is the kernel's name in the compiled program and the trace."""
     if interpret is None:
         interpret = default_interpret()
     n_full, b = y.shape
@@ -384,7 +385,7 @@ def _encode_pallas(core, width, noise_cols, y, noise, fixed_step,
             kernel, grid=grid, in_specs=[y_spec, noise_spec],
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((n, width), jnp.uint8, **vma_kw),
-            interpret=interpret,
+            interpret=interpret, name=f"{name}_adaptive",
         )(y, noise)
 
     def kernel(y_ref, noise_ref, step_ref, payload_ref):
@@ -399,13 +400,13 @@ def _encode_pallas(core, width, noise_cols, y, noise, fixed_step,
         in_specs=[y_spec, noise_spec, SMEM_SCALARS],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n, width), jnp.uint8, **vma_kw),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(y, noise, step_arr)
 
 
-def _combine_pallas(decode, width, payload_self, payload_left, payload_right,
-                    x_tilde, m_agg, w_self, w_side, deamp, interpret,
-                    row_offset, n_rows):
+def _combine_pallas(name, decode, width, payload_self, payload_left,
+                    payload_right, x_tilde, m_agg, w_self, w_side, deamp,
+                    interpret, row_offset, n_rows):
     """Shared fused decode + shadow-update + combine launch; mirrors the
     int8 ``dequant_combine_payload_pallas`` chunk-view discipline exactly
     (chunk-height in-flight payloads read at row 0, full-height persistent
@@ -456,7 +457,7 @@ def _combine_pallas(decode, width, payload_self, payload_left, payload_right,
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs,
         out_specs=(out_row, out_row, out_row), out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(w, payload_self, payload_left, payload_right, x_tilde, m_agg)
 
 
@@ -466,6 +467,7 @@ def subbyte_encode_pallas(y, noise, code_bits, fixed_step=None,
                           interpret=None, row_offset=0, n_rows=None):
     """(n, B) f32 -> (n, B // pack + 2) uint8 bit-packed payload."""
     return _encode_pallas(
+        f"int{code_bits}_encode",
         lambda yt, nt, st: _subbyte_encode_core(yt, nt, st, code_bits,
                                                 kernel=True),
         subbyte_payload_width(y.shape[1], code_bits), y.shape[1],
@@ -480,6 +482,7 @@ def subbyte_combine_pallas(payload_self, payload_left, payload_right,
     """Sub-byte receive side: unpack codes + bf16 scale in-kernel, fused
     with the shadow update + ring combine.  Returns (x_tilde', m', comb)."""
     return _combine_pallas(
+        f"int{code_bits}_combine",
         lambda p, b: _subbyte_decode_core(p, b, code_bits),
         subbyte_payload_width(x_tilde.shape[1], code_bits),
         payload_self, payload_left, payload_right, x_tilde, m_agg,
@@ -493,6 +496,7 @@ def topk_encode_pallas(y, noise, k, fixed_step=None, interpret=None,
     """(n, B) f32 + (n, 2B) noise -> (n, B//8 + k + 2) uint8 sparse payload
     (selection bitmap || int8 values || bf16 scale)."""
     return _encode_pallas(
+        "topk_encode",
         lambda yt, nt, st: _topk_encode_core(yt, nt, st, k, kernel=True),
         topk_payload_width(y.shape[1], k), 2 * y.shape[1],
         y, noise, fixed_step, interpret, row_offset, n_rows)
@@ -506,6 +510,7 @@ def topk_combine_pallas(payload_self, payload_left, payload_right,
     """Top-k receive side: scatter the k values through the bitmap
     in-kernel, fused with the shadow update + ring combine."""
     return _combine_pallas(
+        "topk_combine",
         lambda p, b: _topk_decode_core(p, b, k),
         topk_payload_width(x_tilde.shape[1], k),
         payload_self, payload_left, payload_right, x_tilde, m_agg,

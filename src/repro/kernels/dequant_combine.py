@@ -78,6 +78,7 @@ def dequant_combine_pallas(codes_self, scale_self, codes_left, scale_left,
         out_specs=(row, row, row),
         out_shape=out_shape,
         interpret=interpret,
+        name="int8_dequant_combine_blocks",
     )(w, codes_self, scale_self, codes_left, scale_left, codes_right,
       scale_right, x_tilde, m_agg)
 
@@ -181,4 +182,5 @@ def dequant_combine_payload_pallas(payload_self, payload_left, payload_right,
         out_specs=(out_row, out_row, out_row),
         out_shape=out_shape,
         interpret=interpret,
+        name="int8_combine",
     )(*operands)

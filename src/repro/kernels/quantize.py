@@ -184,6 +184,7 @@ def quantize_blocks_pallas(y: jax.Array, noise: jax.Array,
             out_specs=(row_spec, scale_spec),
             out_shape=out_shape,
             interpret=interpret,
+            name="int8_quantize_blocks_adaptive",
         )(y, noise)
     step_arr = jnp.reshape(jnp.asarray(fixed_step, jnp.float32), (1,))
     y, noise, step_arr = _align_vma(y, noise, step_arr)
@@ -200,6 +201,7 @@ def quantize_blocks_pallas(y: jax.Array, noise: jax.Array,
         out_specs=(row_spec, scale_spec),
         out_shape=out_shape,
         interpret=interpret,
+        name="int8_quantize_blocks",
     )(y, noise, step_arr)
 
 
@@ -246,6 +248,7 @@ def quantize_payload_pallas(y: jax.Array, noise: jax.Array,
             out_shape=jax.ShapeDtypeStruct((n, b + SCALE_BYTES), jnp.uint8,
                                            **vma_kw),
             interpret=interpret,
+            name="int8_encode_adaptive",
         )(y, noise)
     step_arr = jnp.reshape(jnp.asarray(fixed_step, jnp.float32), (1,))
     y, noise, step_arr = _align_vma(y, noise, step_arr)
@@ -258,4 +261,5 @@ def quantize_payload_pallas(y: jax.Array, noise: jax.Array,
         out_shape=jax.ShapeDtypeStruct((n, b + SCALE_BYTES), jnp.uint8,
                                        **vma_kw),
         interpret=interpret,
+        name="int8_encode",
     )(y, noise, step_arr)

@@ -327,7 +327,9 @@ def build_train_setup(
         if ctx.fsdp > 1:
             grads = jax.tree.map(lambda g: g / ctx.fsdp, grads)
         lr_k = sched(k)
-        x_half, opt_state = opt.step(state["opt"], state["params"], grads, lr_k)
+        with jax.named_scope("optimizer"):
+            x_half, opt_state = opt.step(state["opt"], state["params"], grads,
+                                         lr_k)
         # consensus noise stream rooted at the run seed (folded per step;
         # _device_key folds in the node coordinates) — independent runs must
         # not share quantization noise or their stochastic-rounding errors
@@ -490,6 +492,17 @@ def measure_consensus_overhead(setup: TrainSetup, state,
 # CLI
 # ---------------------------------------------------------------------------
 
+def _step_range(text: str) -> tuple[int, int]:
+    """``A:B`` -> (A, B), the steps A to B-1."""
+    try:
+        a, b = (int(t) for t in text.split(":"))
+    except ValueError:
+        raise argparse.ArgumentTypeError(f"expected A:B, got {text!r}")
+    if not 0 <= a < b:
+        raise argparse.ArgumentTypeError(f"need 0 <= A < B, got {text!r}")
+    return a, b
+
+
 def main(argv=None):
     from repro.configs import get_config, reduced
     from repro.data import SyntheticLMDataset
@@ -615,14 +628,24 @@ def main(argv=None):
                     help="structured telemetry (core.telemetry, DESIGN.md "
                          "§Observability): per-step counter records + host "
                          "events to obs/telemetry-{run_id}.jsonl (schema "
-                         "telemetry/v1) and a Chrome/Perfetto span timeline "
-                         "to obs/trace-{run_id}.json; also turns on the "
-                         "in-trace telemetry counters of the exchange")
+                         "telemetry/v1); also turns on the in-trace "
+                         "telemetry counters of the exchange")
     ap.add_argument("--telemetry-dir", default="obs",
                     help="sink directory for --telemetry")
     ap.add_argument("--run-id", default=None,
                     help="telemetry run id (default: a wall-clock stamp)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a JAX profiler trace of --profile-steps "
+                         "here: the device timeline with the program's "
+                         "named scopes, for Perfetto or TensorBoard")
+    ap.add_argument("--profile-steps", type=_step_range, default=(2, 4),
+                    metavar="A:B",
+                    help="with --profile-dir: trace steps A to B-1 "
+                         "(default 2:4, past the compiling first steps)")
     args = ap.parse_args(argv)
+    if args.profile_dir and args.profile_steps[0] >= args.steps:
+        raise SystemExit(f"--profile-steps {args.profile_steps[0]}:... "
+                         f"starts past the run's {args.steps} steps")
     enable_compile_cache()
 
     cfg = get_config(args.arch)
@@ -665,11 +688,8 @@ def main(argv=None):
                 text=True, timeout=5).stdout.strip() or None
         except Exception:
             pass
-        # created BEFORE the setups so the span recorder's trace observer
-        # sees the exchange schedule of the first compiled step
         tel = tele.Telemetry(run_id, out_dir=args.telemetry_dir,
-                             config=dict(vars(args)), git_sha=git_sha,
-                             spans=True)
+                             config=dict(vars(args)), git_sha=git_sha)
         print(f"[telemetry] -> {tel.path}")
 
     setups: dict[str, TrainSetup] = {}
@@ -820,22 +840,28 @@ def main(argv=None):
         tel.event("membership_epoch", step=0, epoch=0,
                   active=int(sum(membership_masks[0])),
                   mask=list(membership_masks[0]))
+    profile_from, profile_to = args.profile_steps
     for step in range(args.steps):
-        batch = jax.device_put(ds.global_batch_arrays(step), setup.batch_sharding)
-        ts = time.perf_counter()
-        state, metrics = setup.train_step(state, batch)
-        jax.block_until_ready(metrics)
-        dur = time.perf_counter() - ts
+        if args.profile_dir and step == profile_from:
+            jax.profiler.start_trace(args.profile_dir)
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            rows = ds.global_batch_arrays(step)
+            with jax.profiler.TraceAnnotation("input.transfer"):
+                batch = jax.device_put(rows, setup.batch_sharding)
+            ts = time.perf_counter()
+            state, metrics = setup.train_step(state, batch)
+            jax.block_until_ready(metrics)
+            dur = time.perf_counter() - ts
+        if args.profile_dir and step + 1 == min(profile_to, args.steps):
+            jax.profiler.stop_trace()
+            print(f"[profile] steps {profile_from}:{step + 1} -> "
+                  f"{args.profile_dir}")
         if step >= 2:                 # skip compile + cache-warm steps
             step_times.append(dur)
         if tel is not None:
             mfloat = {k: float(v) for k, v in metrics.items()}
             mfloat["step_s"] = dur
             tel.record_step(step + 1, mfloat)
-            if step >= 1:   # step 0's window is dominated by compile
-                frac = overhead.get("consensus_overhead_frac", 0.25)
-                tel.spans.record_step_window(step + 1, ts, dur,
-                                             exchange_frac=frac)
             if mfloat.get("resync_fired", 0.0) > 0.5:
                 tel.event("resync", step=step + 1,
                           ok=mfloat.get("resync_ok", 0.0) > 0.5)
@@ -919,8 +945,7 @@ def main(argv=None):
                                if step_times else None),
                   **{k: v for k, v in overhead.items()})
         tel.close()
-        print(f"[telemetry] wrote {tel.path}" +
-              (f" and {tel.trace_path}" if tel.spans is not None else ""))
+        print(f"[telemetry] wrote {tel.path}")
 
 
 if __name__ == "__main__":

@@ -121,10 +121,6 @@ def chunked_attention(
     ck = _divisor_chunk(sk, chunk_k)
     nq, nk = sq // cq, sk // ck
 
-    qc = q.reshape(b, nq, cq, kvh, g, hd).transpose(1, 0, 2, 3, 4, 5)
-    kc = k.reshape(b, nk, ck, kvh, hd).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(b, nk, ck, kvh, hd).transpose(1, 0, 2, 3, 4)
-
     neg = jnp.asarray(-1e30, jnp.float32)
 
     def q_step(_, iq_qi):
@@ -180,9 +176,15 @@ def chunked_attention(
     # (cq, ck) score/probability residuals across iterations — the stacked
     # residuals are the full (sq, sk) matrix in f32 (section Perf, yi-9b).
     q_body = jax.checkpoint(q_step, prevent_cse=False) if sq > cq else q_step
-    _, outs = jax.lax.scan(q_body, None, (jnp.arange(nq), qc))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, kvh, g, hd)
-    return out.astype(q.dtype)
+    # "attention/core": the chunk layout, the two scans and the online
+    # softmax, apart from the projections around them
+    with jax.named_scope("core"):
+        qc = q.reshape(b, nq, cq, kvh, g, hd).transpose(1, 0, 2, 3, 4, 5)
+        kc = k.reshape(b, nk, ck, kvh, hd).transpose(1, 0, 2, 3, 4)
+        vc = v.reshape(b, nk, ck, kvh, hd).transpose(1, 0, 2, 3, 4)
+        _, outs = jax.lax.scan(q_body, None, (jnp.arange(nq), qc))
+        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, kvh, g, hd)
+        return out.astype(q.dtype)
 
 
 def decode_attention_local(

@@ -220,11 +220,12 @@ def _block_forward(code: str, p, x, cfg, ctx, *, mode, cache, pos,
         window_override = None
         if long_serve and code == "A" and cfg.long_context_window:
             window_override = cfg.long_context_window
-        attn_out, c = attention_forward(
-            p["attn"], h, cfg, ctx, kind=code, mode=mode,
-            cache=cache.get("attn") if cache else None, pos_offset=pos,
-            cache_seq_axes=cache_seq_axes, window_override=window_override,
-            use_rope=use_rope)
+        with jax.named_scope("attention"):
+            attn_out, c = attention_forward(
+                p["attn"], h, cfg, ctx, kind=code, mode=mode,
+                cache=cache.get("attn") if cache else None, pos_offset=pos,
+                cache_seq_axes=cache_seq_axes,
+                window_override=window_override, use_rope=use_rope)
         if c is not None:
             new_cache["attn"] = c
     else:
@@ -252,7 +253,8 @@ def _block_forward(code: str, p, x, cfg, ctx, *, mode, cache, pos,
         if "moe" in p:
             ffn_out, aux = moe.moe_forward(p["moe"], h2, cfg, ctx)
         else:
-            ffn_out = mlp_forward(p["mlp"], h2, cfg, ctx)
+            with jax.named_scope("mlp"):
+                ffn_out = mlp_forward(p["mlp"], h2, cfg, ctx)
         if cfg.post_norms:
             ffn_out = rms_norm(ffn_out, p["norm2_post"], cfg.norm_eps)
         x = x + ffn_out
@@ -336,7 +338,8 @@ def model_apply(params, defs: ModelDefs, batch: dict, ctx: ParallelContext,
     tokens = batch["tokens"]
     b, s = tokens.shape
     embed_p = gather_tree(params["embed"], defs.storage["embed"], ctx)
-    x = embed_lookup(embed_p, tokens, cfg, ctx, dtype=compute_dtype)
+    with jax.named_scope("embed"):
+        x = embed_lookup(embed_p, tokens, cfg, ctx, dtype=compute_dtype)
     x = ctx.pvary_tp(x)  # vma consistency for the period-scan carry
 
     enc_out = None
@@ -401,14 +404,18 @@ def model_apply(params, defs: ModelDefs, batch: dict, ctx: ParallelContext,
         body = jax.checkpoint(period_body, prevent_cse=False, policy=policy)
 
     layer_cache = cache["layers"] if cache is not None else None
-    x, (new_layer_cache, aux_per) = jax.lax.scan(
-        body, x, (params["layers"], layer_cache))
+    # "layers": the stack's own work around its blocks' attention and mlp
+    # (norms, residual adds, slicing the stacked weights per layer)
+    with jax.named_scope("layers"):
+        x, (new_layer_cache, aux_per) = jax.lax.scan(
+            body, x, (params["layers"], layer_cache))
     aux_total = aux_total + jnp.sum(aux_per)
 
     final_w = gather_tree({"w": params["final_norm"]},
                           {"w": defs.storage["final_norm"]}, ctx)["w"]
     x = rms_norm(x, final_w, cfg.norm_eps)
-    logits = logits_local(embed_p, x, cfg, ctx)
+    with jax.named_scope("head_loss"):
+        logits = logits_local(embed_p, x, cfg, ctx)
 
     new_cache = None
     if mode in ("prefill", "decode"):
@@ -425,7 +432,8 @@ def train_loss(params, defs: ModelDefs, batch: dict, ctx: ParallelContext,
     logits, _, aux = model_apply(params, defs, batch, ctx, mode="train",
                                  compute_dtype=compute_dtype, remat=remat)
     cfg = defs.cfg
-    loss = sharded_softmax_xent(logits, batch["labels"], cfg, ctx)
+    with jax.named_scope("head_loss"):
+        loss = sharded_softmax_xent(logits, batch["labels"], cfg, ctx)
     # aux is replicated compute but vma-varying over 'model'; it MUST be made
     # invariant before differentiation or every gradient is scaled by tp
     # (grad-inside-shard_map of a varying scalar sums the per-rank replicas).

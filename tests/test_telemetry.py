@@ -15,11 +15,6 @@ Covered contracts:
     registry rejects unregistered metrics, non-finite values and
     negative counters; ``register`` extends the schema via per-record
     ``types``
-  * ``SpanRecorder`` — trace-mark dedup; the pipelined schedule renders
-    in-flight spans that OVERLAP the codec track; the async pending
-    span stays open across the step boundary and covers the next
-    window's compute (the DESIGN §10 overlap claim, host-simulated);
-    Perfetto export carries all five phases
   * JSON-able describe()/event helpers: WireLayout, WirePlan, loss
     models, ``MembershipSchedule.epoch_events``,
     ``AdaptiveBitController.candidate_table``
@@ -205,102 +200,6 @@ def test_telemetry_sink_roundtrip(tmp_path):
     assert recs[1]["types"] == {"my_count": "counter"}
     assert recs[2]["data"] == {"old": "int8", "new": "int4"}
     tel.close()  # idempotent
-
-
-# ---------------------------------------------------------------------------
-# SpanRecorder: schedule capture + Perfetto rendering
-# ---------------------------------------------------------------------------
-
-def _window(sr, step, start_s, dur_s=0.1, frac=0.4):
-    """Render one step window at a synthetic wall-clock offset."""
-    sr.record_step_window(step, sr._origin + start_s, dur_s,
-                          exchange_frac=frac)
-
-
-def test_trace_mark_is_noop_without_observer():
-    telemetry.set_trace_observer(None)
-    telemetry.trace_mark("quantize", 0, rows=3)  # must not raise
-
-
-def test_span_recorder_dedup_and_eager_schedule(tmp_path):
-    sr = telemetry.SpanRecorder().install()
-    try:
-        for _ in range(2):   # lax.switch traces branches twice — dedup
-            for ph in ("quantize", "launch", "retire", "dequant_combine"):
-                telemetry.trace_mark(ph, 0, rows=7)
-    finally:
-        sr.uninstall()
-    assert [(p, u) for p, u, _ in sr.schedule] == [
-        ("quantize", 0), ("launch", 0), ("retire", 0),
-        ("dequant_combine", 0)]
-    _window(sr, 1, 0.0)
-    _window(sr, 2, 0.1)
-    sr.save(str(tmp_path / "trace.json"))
-    trace = json.load(open(tmp_path / "trace.json"))
-    cov = telemetry.trace_phase_coverage(trace)
-    assert all(cov[ph] == 2 for ph in telemetry.SPAN_PHASES), cov
-    # the monolithic packed exchange is SERIAL: its in-flight span sits
-    # between launch and retire inside the exchange window, overlapping
-    # no compute/codec work — no false overlap claims
-    assert not telemetry.trace_has_overlap(trace)
-
-
-def test_span_recorder_pipelined_overlap():
-    """The pipelined schedule interleaves unit c's flight with unit c+1's
-    quantize — the rendered in-flight spans overlap the codec track."""
-    sr = telemetry.SpanRecorder().install()
-    try:
-        telemetry.trace_mark("quantize", 0)
-        telemetry.trace_mark("launch", 0)
-        telemetry.trace_mark("quantize", 1)   # traced while u0 in flight
-        telemetry.trace_mark("launch", 1)
-        telemetry.trace_mark("retire", 0)
-        telemetry.trace_mark("dequant_combine", 0)
-        telemetry.trace_mark("retire", 1)
-        telemetry.trace_mark("dequant_combine", 1)
-    finally:
-        sr.uninstall()
-    _window(sr, 1, 0.0)
-    trace = sr.to_perfetto()
-    cov = telemetry.trace_phase_coverage(trace)
-    assert cov["in_flight"] == 2 and cov["quantize"] == 2, cov
-    assert telemetry.trace_has_overlap(trace)
-
-
-def test_span_recorder_async_pending_crosses_steps():
-    """An async launch with no retire in its window stays OPEN (one span
-    per in-flight buffer) and is closed by the NEXT window's first
-    retire slot — so the flight covers the next step's compute span."""
-    sr = telemetry.SpanRecorder().install()
-    try:
-        telemetry.trace_mark("retire", 0, mode="async")
-        telemetry.trace_mark("dequant_combine", 0)
-        telemetry.trace_mark("quantize", 0, mode="async")
-        telemetry.trace_mark("launch", 0,
-                             buffers=("fly_self", "fly_up", "fly_dn"))
-    finally:
-        sr.uninstall()
-    _window(sr, 1, 0.0)
-    _window(sr, 2, 0.1)
-    trace = sr.to_perfetto()   # also closes window 2's still-open flight
-    names = [e["name"] for e in trace["traceEvents"] if e.get("ph") == "X"]
-    assert names.count("in_flight fly_up") == 2
-    cov = telemetry.trace_phase_coverage(trace)
-    assert cov["in_flight"] == 6 and cov["retire"] == 2, cov
-    assert telemetry.trace_has_overlap(trace)
-    # every record well-formed enough for Perfetto: X events need dur >= 0
-    for ev in trace["traceEvents"]:
-        if ev.get("ph") == "X":
-            assert ev["dur"] > 0 and "tid" in ev
-
-
-def test_host_span_context_manager():
-    sr = telemetry.SpanRecorder()
-    with sr.span("controller decide", args={"epoch": 3}):
-        pass
-    ev = sr.to_perfetto()["traceEvents"][-1]
-    assert ev["name"] == "controller decide" and ev["cat"] == "host"
-    assert ev["tid"] == telemetry.TRACKS["host"]
 
 
 # ---------------------------------------------------------------------------

@@ -943,15 +943,12 @@ def test_telemetry_off_is_free():
     """Acceptance (telemetry satellite): with ``telemetry=False`` (the
     default) the step jaxpr is BIT-IDENTICAL to a telemetry-less build —
     no extra metric outputs, no extra ops, exactly 2 ring ppermutes —
-    on the packed AND async transports.  Installing a SpanRecorder
-    (trace-time marks only) must not change the jaxpr either, while
-    still capturing the full exchange schedule.  The telemetry-off
-    metric keyset is pinned so new always-on metrics cannot sneak in."""
+    on the packed AND async transports.  The telemetry-off metric
+    keyset is pinned so new always-on metrics cannot sneak in."""
     body = """
 import sys
 sys.path.insert(0, os.path.join(%r, "benchmarks"))
 from consensus_step import count_eqns
-from repro.core import telemetry as tele
 
 tree = make_tree(jax.random.PRNGKey(4))
 out = {}
@@ -983,14 +980,9 @@ for mode in ("packed", "async"):
     kw = dict(algorithm="adc_dgd", wire_packing=mode)
     j_default, keys_default = jaxpr_and_keys(kw)
     j_off, _ = jaxpr_and_keys({**kw, "telemetry": False})
-    sr = tele.SpanRecorder().install()
-    j_obs, _ = jaxpr_and_keys(kw)
-    sr.uninstall()
     out[f"{mode}_default_eq_off"] = str(j_default) == str(j_off)
-    out[f"{mode}_default_eq_observed"] = str(j_default) == str(j_obs)
     out[f"{mode}_ppermutes"] = count_eqns(j_default, "ppermute")
     out[f"{mode}_metric_keys"] = keys_default
-    out[f"{mode}_marks"] = sorted(set(p for p, _, _ in sr.schedule))
     cfg = ConsensusConfig(**kw)
     out[f"{mode}_extra_keys"] = list(cfg.telemetry_metric_keys())
     on = ConsensusConfig(**kw, telemetry=True)
@@ -1006,14 +998,9 @@ print("RESULT", json.dumps(out))
     for mode in ("packed", "async"):
         assert r[f"{mode}_default_eq_off"], \
             f"{mode}: default != explicit telemetry=False jaxpr"
-        assert r[f"{mode}_default_eq_observed"], \
-            f"{mode}: installing the span observer changed the jaxpr"
         assert r[f"{mode}_ppermutes"] == 2, r
         # frozen telemetry-off metric keyset: any always-on addition
         # must consciously update this pin (it costs every user)
         assert r[f"{mode}_metric_keys"] == pinned, r
         assert r[f"{mode}_extra_keys"] == [], r
         assert r[f"{mode}_on_adds_exactly"], r
-        # the observer saw the full exchange schedule without touching it
-        assert r[f"{mode}_marks"] == ["dequant_combine", "launch",
-                                      "quantize", "retire"], r
