@@ -23,6 +23,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..kernels.flash_attention import flash_attention
+from ..kernels.flash_attention import supports as flash_attention_supports
+from ..kernels.quantize import default_interpret
 from .config import ModelConfig
 from .params import ParamDef
 from .sharding import ParallelContext
@@ -111,7 +114,6 @@ def chunked_attention(
     k_offset: int = 0,
     chunk_q: int = 512,
     chunk_k: int = 1024,
-    block_skip: bool = False,       # skip fully-masked kv blocks (perf opt)
 ) -> jax.Array:
     """Online-softmax attention over chunks.  Returns (b, sq, kvh, g, hd)."""
     b, sq, kvh, g, hd = q.shape
@@ -160,14 +162,8 @@ def chunked_attention(
         l0 = qz[..., 0]
         a0 = qz
 
-        iks = jnp.arange(nk)
-        if block_skip and causal and nk > 1:
-            # process only kv blocks that can be visible to this q block:
-            # blocks with start <= last q position.  Implemented by masking
-            # whole blocks via lax.cond-free select (cheap vs the matmul).
-            pass  # handled by the mask already; true skipping is in the
-                  # Pallas kernel / perf variants.
-        (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), (iks, kc, vc))
+        (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0),
+                                      (jnp.arange(nk), kc, vc))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return None, out.transpose(0, 3, 1, 2, 4)       # (b, cq, kvh, g, hd)
 
@@ -185,6 +181,41 @@ def chunked_attention(
         _, outs = jax.lax.scan(q_body, None, (jnp.arange(nq), qc))
         out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, kvh, g, hd)
         return out.astype(q.dtype)
+
+
+def attention_path(q_shape, k_shape, *, causal: bool, window: int | None,
+                   softcap: float | None, q_offset, k_offset) -> str:
+    """Which attention core runs: ``"kernel"`` (the Pallas flash-attention
+    kernels) on a TPU, for causal self-attention from position 0 with no
+    window or soft-cap and shapes the kernels take; ``"chunked"``
+    (``chunked_attention``) for everything else: the CPU, a traced offset
+    (the sequence-sharded path), non-causal attention (whisper's encoder),
+    gemma2's window and soft-cap, cross attention over another length."""
+    _, sq, _, g, hd = q_shape
+    static_zero = all(isinstance(o, int) and o == 0
+                      for o in (q_offset, k_offset))
+    if (default_interpret() or not causal or window is not None
+            or softcap is not None or not static_zero or k_shape[1] != sq
+            or not flash_attention_supports(sq, g, hd)):
+        return "chunked"
+    return "kernel"
+
+
+def attention_core(q, k, v, *, causal: bool = True, window: int | None = None,
+                   softcap: float | None = None,
+                   q_offset: jax.Array | int = 0,
+                   k_offset: int = 0) -> jax.Array:
+    """Self-attention core on the path ``attention_path`` picks; q: (b, sq,
+    kvh, g, hd), k/v: (b, sk, kvh, hd) -> (b, sq, kvh, g, hd)."""
+    if attention_path(q.shape, k.shape, causal=causal, window=window,
+                      softcap=softcap, q_offset=q_offset,
+                      k_offset=k_offset) == "kernel":
+        # "attention/core", as chunked_attention's own scope
+        with jax.named_scope("core"):
+            return flash_attention(q, k, v)
+    return chunked_attention(q, k, v, causal=causal, window=window,
+                             softcap=softcap, q_offset=q_offset,
+                             k_offset=k_offset)
 
 
 def decode_attention_local(
@@ -353,8 +384,8 @@ def attention_forward(
             q = apply_rope(q.reshape(b, s, -1, q.shape[-1]), pos, cfg.rope_theta
                            ).reshape(q.shape)
             k = apply_rope(k, pos, cfg.rope_theta)
-        out = chunked_attention(q, k, v, causal=causal, window=window,
-                                softcap=softcap, q_offset=pos_offset)
+        out = attention_core(q, k, v, causal=causal, window=window,
+                             softcap=softcap, q_offset=pos_offset)
         out = out.reshape(b, s, -1)
         y = ctx.psum_tp(out @ p["wo"])
         new_cache = None
@@ -380,10 +411,9 @@ def attention_forward(
         k = apply_rope(k, pos_chunk, cfg.rope_theta)
     k_full = ctx.ag_tp(k, axis=1)
     v_full = ctx.ag_tp(v, axis=1)
-    out = chunked_attention(q, k_full, v_full, causal=causal, window=window,
-                            softcap=softcap,
-                            q_offset=pos_offset + r * s_local,
-                            k_offset=0)
+    out = attention_core(q, k_full, v_full, causal=causal, window=window,
+                         softcap=softcap, q_offset=pos_offset + r * s_local,
+                         k_offset=0)
     out = out.reshape(b, s_local, -1)
     y_chunk = out @ p["wo"]
     y = ctx.ag_tp(y_chunk, axis=1)

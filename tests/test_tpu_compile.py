@@ -5,12 +5,13 @@ described, so these tests catch what interpret mode cannot: kernels Mosaic
 refuses to lower, and a sharded train step that does not compile for four
 chips.  They prove nothing about results or times.
 
-Every wire kernel is compiled at smollm-135m's one-chip packed width, and
-the four-chip ADC-DGD train step is compiled on a mesh of the described
-devices.  The topology is described inside a fixture (only one process may
-load the TPU library at a time), and the persistent compilation cache stays
-off in this file: an entry compiled for a described chip cannot be read
-back here.
+Every wire kernel is compiled at smollm-135m's one-chip packed width, the
+flash-attention kernels at the attention shapes of both benchmark cells,
+and the four-chip ADC-DGD train step on a mesh of the described devices.
+The topology is described inside a fixture (only one process may load the
+TPU library at a time), and the persistent compilation cache stays off in
+this file: an entry compiled for a described chip cannot be read back
+here.
 """
 import os
 import re
@@ -23,9 +24,11 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import bitpack, ops
+from repro.kernels import flash_attention as FA
 from repro.kernels.dequant_combine import dequant_combine_payload_pallas
 from repro.kernels.quantize import BLOCK, SCALE_BYTES, quantize_payload_pallas
 from repro.launch import train as LT
+from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.sharding import ParallelContext
 
@@ -144,3 +147,32 @@ def test_four_chip_adc_train_step_compiles(topo, monkeypatch):
     assert "tpu_custom_call" in text
     permutes = re.findall(r" collective-permute(?:-start)?\(", text)
     assert len(permutes) == 2, permutes
+
+
+@pytest.mark.parametrize("b,s,kvh,g,hd", [(8, 2048, 3, 3, 64),
+                                          (1, 4096, 8, 2, 128)],
+                         ids=["smollm-135m", "qwen3-0.6b"])
+def test_flash_attention_compiles(one_chip, monkeypatch, b, s, kvh, g, hd):
+    """A layer's attention core at the cells' shapes, differentiated under
+    the layer's full remat, compiles to the named kernels: the forward for
+    the forward pass and again for the recompute, dq and dkv once, each
+    under the ``attention/core`` scope the trace reduction reads."""
+    monkeypatch.setattr(L, "default_interpret", lambda: False)
+    monkeypatch.setattr(FA, "default_interpret", lambda: False)
+
+    def loss(q, k, v):
+        with jax.named_scope("attention"):
+            return jnp.sum(L.attention_core(q, k, v) ** 2)
+
+    shapes = [(b, s, kvh, g, hd), (b, s, kvh, hd), (b, s, kvh, hd)]
+    args = [jax.ShapeDtypeStruct(x, jnp.float32, sharding=one_chip)
+            for x in shapes]
+    text = _hlo(jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1, 2)),
+                *args)
+    calls = re.findall(r"%(flash_attn_\w+)\.\d+ = .*tpu_custom_call.*"
+                       r"op_name=\"([^\"]*)\"", text)
+    assert sorted(n for n, _ in calls) == [
+        "flash_attn_dkv", "flash_attn_dq", "flash_attn_fwd", "flash_attn_fwd"]
+    # jvp(attention)/core/... in the forward, .../attention/core/... else
+    assert all(re.search(r"attention\)*/core/flash_attn_", path)
+               for _, path in calls), calls
