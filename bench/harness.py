@@ -4,8 +4,11 @@ A cell is a model configuration (``bench/configs/<config>.json``) under a
 traffic mix (``bench/traffic/<traffic>.json``: the training job's nodes,
 mesh, rows per node and exchange settings), named in ``BENCHMARK.json``;
 its correctness limits sit in ``bench/workloads/<cell>.json``.  Per-layer
-metrics are readers in ``bench/metrics/<metric>.py``.  Nothing here names a
-cell, a configuration or a metric: a new one is a new file.
+metrics are readers in ``bench/metrics/<metric>.py``.  A configuration
+names its plain reference module (``bench/<reference>.py``, default
+``bench/reference.py``), which also checks the file against the program
+and counts the model's operations.  Nothing here names a cell, a
+configuration, an architecture or a metric: a new one is a new file.
 
 The timed path is the program's normal one:
 ``build_train_setup`` -> ``init_train_state(setup, seed)`` -> the compiled
@@ -16,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib
 import json
 import math
 import sys
@@ -26,7 +30,6 @@ from pathlib import Path
 import numpy as np
 
 from bench import correct as C
-from bench import counts
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -110,21 +113,21 @@ class CompileCounter:
 # the system under test
 # ---------------------------------------------------------------------------
 
+def reference_module(conf: dict):
+    """The configuration's plain reference module: ``bench/<name>.py`` for
+    its ``reference`` key, ``bench/reference.py`` without one."""
+    name = conf.get("reference", "reference")
+    return importlib.import_module(f"bench.{name}")
+
+
 def model_config(conf: dict):
     """The program's ModelConfig for a configuration file, checked against
-    the file's published keys."""
+    the file's published keys by its reference module."""
     from repro.configs import get_config
     cfg = dataclasses.replace(get_config(conf["arch"]),
                               **conf.get("program", {}))
-    want = {"hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
-            "num_hidden_layers": cfg.n_layers,
-            "num_attention_heads": cfg.n_heads,
-            "num_key_value_heads": cfg.n_kv_heads,
-            "head_dim": cfg.resolved_head_dim, "vocab_size": cfg.vocab_size,
-            "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
-            "tie_word_embeddings": cfg.tie_embeddings}
-    bad = {k: (conf[k], v) for k, v in want.items() if conf[k] != v}
-    if bad or cfg.qk_norm != (conf["model_type"] == "qwen3"):
+    bad = reference_module(conf).check_program(conf, cfg)
+    if bad:
         raise SystemExit(f"bench: {conf['arch']} as the program builds it "
                          f"differs from its file: {bad}")
     return cfg
@@ -319,9 +322,10 @@ def memory(sys_: System, devices) -> dict:
 # ---------------------------------------------------------------------------
 
 def reference(files: dict, seed: int, rows: list, precision: str = "f32"):
-    """The plain reference trained on ``rows`` from ``seed``."""
-    from bench import reference as R
+    """The configuration's plain reference trained on ``rows`` from
+    ``seed``."""
     conf = files["config"]
+    R = reference_module(conf)
     return R.run_reference(R.Model.from_config(conf), conf["optimizer"],
                            seed, rows, precision)
 
@@ -331,7 +335,13 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
     """Set-up, window and correctness of one cell; returns the result line
     and the numbers compared."""
     import jax
-    from bench import trace as TR
+    from bench import scopes as SC
+    if trace:
+        # scope names live in the HLO's metadata, which the compilation
+        # cache's key leaves out by default: an executable of the same ops
+        # under other scopes would be served, and its text read
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     counter = CompileCounter()
     sys_, state = build_system(files, seed, step_wrapper)
     state, prog, rows, _ = drive_check_steps(sys_, state)
@@ -355,13 +365,14 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
            "tokens_per_step": sys_.tokens_per_step, "chips": chips,
            "tokens_per_s_per_chip": tokens_per_s_per_chip,
            "outputs": win["outputs"],
-           "flops_per_token": counts.flops_per_token(
-               files["config"], files["traffic"]["seq_len"]),
+           "flops_per_token": reference_module(files["config"])
+           .flops_per_token(files["config"], files["traffic"]["seq_len"]),
            "peaks": peaks_for(device["kind"]) if device["platform"] == "tpu"
            else None}
     reduced = None
     if tmp is not None:
-        reduced = TR.reduce(TR.load(tmp.name), chips)
+        reduced = SC.reduce(SC.load(tmp.name),
+                            SC.op_names(sys_.compiled.as_text()), chips)
         tmp.cleanup()
         obs["trace"] = reduced
     out_state = win.pop("state")
@@ -386,8 +397,7 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
             v = read_metric(m["name"], obs)
             if v is not None:
                 result["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
-        result["breakdown"] = {"device_ops": reduced["top_ops"],
-                               "idle_gaps": reduced["idle_gaps"]}
+        result["breakdown"] = reduced["breakdown"]
     else:
         e2e = {"tokens_per_s_per_chip": tokens_per_s_per_chip,
                "peak_hbm_gib": mem["peak_bytes"] / 2 ** 30,

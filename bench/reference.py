@@ -1,9 +1,16 @@
-"""Plain reference of one node's training, as its configuration states it.
+"""Plain reference of the Llama/Qwen3 family's training, as a
+configuration states it.
 
 Written from the configuration alone: it imports nothing of the program
 under test and takes nothing the program made.  It draws its own weights
 from the seed by the initialisation the configuration states and trains on
 the same token rows the timed path was fed, with Adam.
+
+A configuration file names its reference module under ``reference``
+(``bench/<reference>.py``; this one where the key is absent).  Every such
+module gives what the harness asks of a model: ``Model.from_config``,
+``run_reference``, ``check_program`` (the file's published keys against
+the program's ModelConfig) and ``flops_per_token`` / ``param_count``.
 
 The configurations state float32 weights, activations, optimizer state and
 accumulation, with float32 matrix products at the TPU's default precision
@@ -31,7 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Model", "leaf_specs", "init_params", "run_reference"]
+__all__ = ["Model", "leaf_specs", "init_params", "run_reference",
+           "check_program", "matmul_params", "param_count",
+           "flops_per_token"]
 
 ROW_BLOCK = 1024          # rows of a vocabulary-head block
 QUERY_BLOCK = 512         # query rows of an attention block
@@ -314,3 +323,49 @@ def run_reference(m: Model, opt: dict, seed: int,
     change = jax.tree.map(np.asarray, _diff(p, x0))
     return {"loss": [float(v) for v in losses], "grad1": grad1,
             "change": change}
+
+
+# ---------------------------------------------------------------------------
+# the configuration file against the program, and the model's counts
+# ---------------------------------------------------------------------------
+
+def check_program(conf: dict, cfg) -> dict:
+    """The published keys of ``conf`` that the program's ModelConfig
+    ``cfg`` builds otherwise, as {key: (file, program)}."""
+    built = {"hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
+             "num_hidden_layers": cfg.n_layers,
+             "num_attention_heads": cfg.n_heads,
+             "num_key_value_heads": cfg.n_kv_heads,
+             "head_dim": cfg.resolved_head_dim, "vocab_size": cfg.vocab_size,
+             "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
+             "tie_word_embeddings": cfg.tie_embeddings}
+    bad = {k: (conf[k], v) for k, v in built.items() if conf[k] != v}
+    if cfg.qk_norm != (conf["model_type"] == "qwen3"):
+        bad["model_type"] = (conf["model_type"], f"qk_norm={cfg.qk_norm}")
+    return bad
+
+
+def matmul_params(conf: dict) -> int:
+    """Matrix weights, each once; the (tied) vocabulary head once."""
+    m = Model.from_config(conf)
+    q = m.num_attention_heads * m.head_dim
+    kv = m.num_key_value_heads * m.head_dim
+    d = m.hidden_size
+    per_layer = 2 * d * q + 2 * d * kv + 3 * d * m.intermediate_size
+    return m.num_hidden_layers * per_layer + m.vocab_size * d
+
+
+def param_count(conf: dict) -> int:
+    """Every parameter: matrices and norm gains (``leaf_specs``)."""
+    return sum(math.prod(shape) for _, shape, _ in
+               leaf_specs(Model.from_config(conf)))
+
+
+def flops_per_token(conf: dict, seq_len: int) -> float:
+    """Model FLOPs per training token (PaLM, Chowdhery et al. 2022, app.
+    B): ``6 N + 12 L H Q T``, with N the matrix weights (``matmul_params``),
+    L layers, H query heads of size Q and T the sequence length.
+    Recomputed operations are not counted."""
+    m = Model.from_config(conf)
+    return (6.0 * matmul_params(conf) + 12.0 * m.num_hidden_layers
+            * m.num_attention_heads * m.head_dim * seq_len)

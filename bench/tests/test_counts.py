@@ -1,4 +1,5 @@
-"""The count functions against the program's own shapes."""
+"""The count functions (bench/counts.py, and the Llama/Qwen3 reference
+module's model counts) against the program's own shapes."""
 import math
 
 import jax
@@ -27,7 +28,7 @@ def test_param_count_matches_program(name, layers, want):
     conf = H.load_json(H.BENCH / "configs" / f"{name}.json")
     conf = dict(conf, num_hidden_layers=layers,
                 program={"n_periods": layers})
-    assert counts.param_count(conf) == want
+    assert R.param_count(conf) == want
     assert _program_params(conf) == want
     specs = R.leaf_specs(R.Model.from_config(conf))
     assert sum(math.prod(s) for _, s, _ in specs) == want
@@ -35,9 +36,9 @@ def test_param_count_matches_program(name, layers, want):
 
 def test_flops_per_token():
     conf = H.load_json(H.BENCH / "configs" / "smollm-135m.json")
-    n = counts.matmul_params(conf)
+    n = R.matmul_params(conf)
     assert n == 134_479_872
-    assert counts.flops_per_token(conf, 2048) == 6 * n + 12 * 30 * 9 * 64 * 2048
+    assert R.flops_per_token(conf, 2048) == 6 * n + 12 * 30 * 9 * 64 * 2048
 
 
 def test_wire_rows_and_kernel_bytes():

@@ -179,3 +179,28 @@ def test_recorded_scoped_trace():
     assert any(k.endswith("/attention/core") for k in r["scopes"])
     assert all(" " in n for n, _ in r["breakdown"]["device_ops"])
     assert r["program_spans"]["input.build"]
+
+
+def test_run_cell_reduces_by_scope(monkeypatch):
+    """A traced run of a cell (CPU size) whose trace is the recorded v5e
+    one, with its op-name map: the result carries the seven scope metrics
+    and the breakdown by scope."""
+    import time
+    from bench.tests.tiny import tiny_files
+    with gzip.open(DATA / "v5e_smollm_node_scoped.json.gz", "rt") as f:
+        raw = json.load(f)
+    raw["devices"] = {int(k): v for k, v in raw["devices"].items()}
+    monkeypatch.setattr(S, "load", lambda d: raw)
+    monkeypatch.setattr(S, "op_names", lambda text: raw["op_names"])
+    files = tiny_files("smollm-135m.chip1.local")
+    files["per_layer"] = H.load_json(H.ROOT / "BENCHMARK.json")["per_layer"]
+    r = H.run_cell(files, 2**31 + 3, 0.5, True, time.perf_counter(),
+                   H.device_info(1))
+    assert r["correct"], r["compared"]
+    got = set(r["metrics"])
+    assert {"step.forward_ms", "step.backward_ms", "step.recompute_ms",
+            "step.optimizer_ms", "step.attention_ms", "step.head_loss_ms",
+            "input.build_ms", "input.host_ms", "device.idle_frac"} <= got
+    assert r["breakdown"] == S.reduce(raw, raw["op_names"], 1)["breakdown"]
+    assert r["device"]["busy_s"] > 0
+    assert list(r)[-1] == "compared"
