@@ -7,8 +7,10 @@ its correctness limits sit in ``bench/workloads/<cell>.json``.  Per-layer
 metrics are readers in ``bench/metrics/<metric>.py``.  A configuration
 names its plain reference module (``bench/<reference>.py``, default
 ``bench/reference.py``), which also checks the file against the program
-and counts the model's operations.  Nothing here names a cell, a
-configuration, an architecture or a metric: a new one is a new file.
+and counts the model's operations; a cell of several nodes trains that
+reference on each node and mixes the nodes as the program's exchange
+states (``bench/ring.py``).  Nothing here names a cell, a configuration,
+an architecture, a kernel or a metric: a new one is a new file.
 
 The timed path is the program's normal one:
 ``build_train_setup`` -> ``init_train_state(setup, seed)`` -> the compiled
@@ -20,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import importlib
+import importlib.util
 import json
 import math
 import sys
@@ -29,7 +32,9 @@ from pathlib import Path
 
 import numpy as np
 
+import bench
 from bench import correct as C
+from bench import counts
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -52,9 +57,6 @@ def cell_files(name: str) -> dict:
     cell = cells[name]
     confs = {c["name"]: c for c in spec["configs"]}
     traffic = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
-    if traffic["nodes"] != 1:
-        raise SystemExit("bench: the reference trains one node; a cell of "
-                         "several nodes needs its exchange first")
     return {
         "cell": cell,
         "config": load_json(ROOT / confs[cell["config"]]["file"]),
@@ -142,6 +144,24 @@ class System:
     compiled: object      # the compiled train step itself
     names: list           # leaf names of the parameter tree, in order
     tokens_per_step: int
+    nodes: int = 1
+    #: per leaf, the logical shape and the dimension on which a leaf of
+    #: the train state stacks its nodes' replicas
+    stacking: list = dataclasses.field(default_factory=list)
+
+    def by_node(self, leaves: list) -> dict:
+        """The leaves of a parameter-shaped tree as host arrays by name:
+        ``<leaf>`` on one node, ``node<i>.<leaf>`` for each node of a
+        ring."""
+        if self.nodes == 1:
+            return {n: np.asarray(a) for n, a in zip(self.names, leaves)}
+        out = {}
+        for name, (shape, dim), a in zip(self.names, self.stacking, leaves):
+            for i, part in enumerate(np.split(np.asarray(a), self.nodes,
+                                              axis=dim)):
+                out[f"node{i}.{name}"] = part[tuple(slice(0, n)
+                                                    for n in shape)]
+        return out
 
     def feed(self, k: int, keep: list | None = None):
         import jax
@@ -161,6 +181,7 @@ def build_system(files: dict, seed: int, step_wrapper=None) -> tuple:
     from repro.data import SyntheticLMDataset
     from repro.launch import train as LT
     from repro.launch.mesh import make_cpu_mesh
+    from repro.models.params import ParamDef
 
     conf, tr = files["config"], files["traffic"]
     cfg = model_config(conf)
@@ -175,7 +196,10 @@ def build_system(files: dict, seed: int, step_wrapper=None) -> tuple:
         schedule=opt["schedule"], lr=opt["lr"], use_pallas=ex["use_pallas"],
         global_batch=batch, seq_len=tr["seq_len"],
         wire_packing=ex["wire_packing"], wire_codec=ex["wire_codec"],
-        seed=seed)
+        # the program bakes its noise seed into the step: a ring states
+        # one, so that one compiled step serves every --seed; one node
+        # draws no noise
+        seed=ex.get("noise_seed", seed))
     o = setup.optimizer
     stated = (opt["b1"], opt["b2"], opt["eps"], ex["self_weight"])
     built = (o.b1, o.b2, o.eps, setup.consensus.cfg.self_weight)
@@ -186,9 +210,12 @@ def build_system(files: dict, seed: int, step_wrapper=None) -> tuple:
     ds = SyntheticLMDataset(cfg.vocab_size, tr["seq_len"], batch, seed=seed,
                             n_shards=setup.ctx.dp)
     paths = jax.tree_util.tree_flatten_with_path(state["params"])[0]
+    defs = jax.tree.leaves(setup.defs.storage,
+                           is_leaf=lambda x: isinstance(x, ParamDef))
     sys_ = System(setup=setup, dataset=ds, step=None, compiled=None,
                   names=[leaf_name(p) for p, _ in paths],
-                  tokens_per_step=batch * tr["seq_len"])
+                  tokens_per_step=batch * tr["seq_len"], nodes=nodes,
+                  stacking=[(d.shape, d.fsdp_dim) for d in defs])
     example = {k: jax.ShapeDtypeStruct((batch, tr["seq_len"]), np.int32,
                                        sharding=sh)
                for k, sh in setup.batch_sharding.items()}
@@ -212,17 +239,18 @@ def drive_check_steps(sys_: System, state) -> tuple:
                          "from Adam's first moment; the optimizer has none")
     for k in range(CHECK_STEPS):
         state, metrics = sys_.step(state, sys_.feed(k, keep=rows))
+        # the mean over the nodes
         losses.append(float(metrics["loss"]))
         if k == 0:
             # Adam's first moment after one step is (1 - b1) * g
             b1 = np.float32(1.0) - np.float32(sys_.setup.optimizer.b1)
-            grad1 = [np.asarray(m) / b1
-                     for m in jax.tree.leaves(jax.device_get(state["opt"]["m"]))]
+            grad1 = {n: m / b1 for n, m in sys_.by_node(jax.tree.leaves(
+                jax.device_get(state["opt"]["m"]))).items()}
     jax.block_until_ready(state)
-    params3 = jax.tree.leaves(jax.device_get(state["params"]))
-    prog = {"loss": losses, "grad1": dict(zip(sys_.names, grad1)),
-            "change": {n: b - a for n, a, b in
-                       zip(sys_.names, params0, params3)}}
+    params0 = sys_.by_node(params0)
+    params3 = sys_.by_node(jax.tree.leaves(jax.device_get(state["params"])))
+    prog = {"loss": losses, "grad1": grad1,
+            "change": {n: params3[n] - a for n, a in params0.items()}}
     return state, prog, [(r["tokens"], r["labels"]) for r in rows], metrics
 
 
@@ -321,13 +349,34 @@ def memory(sys_: System, devices) -> dict:
 # one run
 # ---------------------------------------------------------------------------
 
-def reference(files: dict, seed: int, rows: list, precision: str = "f32"):
+def reference(files: dict, seed: int, rows: list, precision: str = "f32",
+              exchange: bool = True):
     """The configuration's plain reference trained on ``rows`` from
-    ``seed``."""
-    conf = files["config"]
+    ``seed``: one node, or each node of a ring on its own rows of each
+    step, mixed as the traffic's exchange states (without ``exchange``,
+    each node alone)."""
+    conf, tr = files["config"], files["traffic"]
     R = reference_module(conf)
-    return R.run_reference(R.Model.from_config(conf), conf["optimizer"],
-                           seed, rows, precision)
+    if tr["nodes"] == 1:
+        return R.run_reference(R.Model.from_config(conf), conf["optimizer"],
+                               seed, rows, precision)
+    from bench import ring
+    return ring.run_ring(R, conf, seed, ring.node_rows(rows, tr["nodes"]),
+                         tr, precision, exchange)
+
+
+def kernel_counts(files: dict) -> dict:
+    """Per kernel, the flops and bytes one call needs on one chip of the
+    cell (bench/counts.py), with the reference module's own kernels."""
+    conf, tr = files["config"], files["traffic"]
+    R = reference_module(conf)
+    # the wire kernels' rows, on a ring
+    sizes = [math.prod(shape) for _, shape, _ in R.leaf_specs(
+        R.Model.from_config(conf))] if tr["nodes"] > 1 else []
+    out = counts.kernel_counts(conf, tr, sizes)
+    if hasattr(R, "kernel_counts"):
+        out.update(R.kernel_counts(conf, tr))
+    return out
 
 
 def run_cell(files: dict, seed: int, seconds: float, trace: bool,
@@ -367,6 +416,7 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
            "outputs": win["outputs"],
            "flops_per_token": reference_module(files["config"])
            .flops_per_token(files["config"], files["traffic"]["seq_len"]),
+           "kernel_counts": kernel_counts(files),
            "peaks": peaks_for(device["kind"]) if device["platform"] == "tpu"
            else None}
     reduced = None
@@ -414,8 +464,10 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
 
 
 def read_metric(name: str, obs: dict):
-    import importlib.util
-    path = BENCH / "metrics" / f"{name}.py"
+    """The reader ``metrics/<name>.py`` of the ``bench`` package's first
+    directory that holds one, applied to ``obs``."""
+    path = next(p for p in (Path(d) / "metrics" / f"{name}.py"
+                            for d in bench.__path__) if p.exists())
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
@@ -427,13 +479,14 @@ def read_metric(name: str, obs: dict):
 # readings for the limits: the program on many seeds, and the control
 # ---------------------------------------------------------------------------
 
-def _halve(rows: list[tuple]) -> list[tuple]:
-    """The second half of each step's rows replaced by the first: half of
-    the batch left out, the mean taken over the rest."""
+def _halve(rows: list[tuple], nodes: int = 1) -> list[tuple]:
+    """The second half of each node's rows of each step replaced by its
+    first: half of the batch left out, the mean taken over the rest."""
     def half(a):
         a = a.copy()
-        b = len(a) // 2
-        a[b:2 * b] = a[:b]
+        for part in np.split(a, nodes):
+            b = len(part) // 2
+            part[b:2 * b] = part[:b]
         return a
     return [(half(t), half(l)) for t, l in rows]
 
@@ -442,10 +495,12 @@ def readings(files: dict, seeds: list[int], stand_in: str | None = None,
              step_wrapper=None, out_path: str | None = None) -> list[dict]:
     """The numbers compared, without a window, per seed: the program's
     first steps against the reference, or (``stand_in``) the reference
-    computed in the precision below the configuration's or with a fault
-    planted, in the program's place.  One process, so the compiled
-    programs are reused.  Each seed's record, with the per-leaf norms the
-    numbers are read from, is printed and appended to ``out_path``."""
+    computed in the precision below the configuration's (``control``), or
+    with half of each node's rows left out (``half_batch``) or with the
+    exchange between the nodes left out (``no_exchange``), in the
+    program's place.  One process, so the compiled programs are reused.
+    Each seed's record, with the per-leaf norms the numbers are read from,
+    is printed and appended to ``out_path``."""
     from repro.data import SyntheticLMDataset
     tr = files["traffic"]
     out = []
@@ -458,13 +513,17 @@ def readings(files: dict, seeds: list[int], stand_in: str | None = None,
             gc.collect()
         else:
             ds = SyntheticLMDataset(files["config"]["vocab_size"],
-                                    tr["seq_len"], tr["seqs_per_node"],
-                                    seed=seed, n_shards=1)
+                                    tr["seq_len"],
+                                    tr["nodes"] * tr["seqs_per_node"],
+                                    seed=seed, n_shards=tr["mesh"]["data"])
             rows = [(r["tokens"], r["labels"]) for r in
                     (ds.global_batch_arrays(k) for k in range(CHECK_STEPS))]
-            prog = reference(
-                files, seed, _halve(rows) if stand_in == "half_batch" else rows,
-                CONTROL if stand_in == "control" else "f32")
+            fed = rows
+            if stand_in == "half_batch":
+                fed = _halve(rows, tr["nodes"])
+            prog = reference(files, seed, fed,
+                             CONTROL if stand_in == "control" else "f32",
+                             exchange=stand_in != "no_exchange")
         ref = reference(files, seed, rows)
         numbers, table = C.compare(prog, ref, files["limits"])
         rec = {"seed": seed, "stand_in": stand_in,

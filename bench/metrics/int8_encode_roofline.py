@@ -1,0 +1,8 @@
+"""Share of its roofline that the ``int8_encode`` kernel reached in the
+traced window: the least time a call needs (bench/counts.py) over the
+time a call took, in percent."""
+from bench.counts import roofline_share
+
+
+def read(obs):
+    return roofline_share(obs, "int8_encode")

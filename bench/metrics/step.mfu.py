@@ -1,5 +1,5 @@
 """Model FLOP utilisation of the whole train step: model FLOPs per token
-(bench/counts.py, recompute not counted) times the traced window's
+(the reference module, recompute not counted) times the traced window's
 training tokens per second per chip, over the chip's bf16 peak
 (bench/peaks.json), in percent."""
 

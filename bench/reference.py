@@ -10,7 +10,11 @@ A configuration file names its reference module under ``reference``
 (``bench/<reference>.py``; this one where the key is absent).  Every such
 module gives what the harness asks of a model: ``Model.from_config``,
 ``run_reference``, ``check_program`` (the file's published keys against
-the program's ModelConfig) and ``flops_per_token`` / ``param_count``.
+the program's ModelConfig) and ``flops_per_token`` / ``param_count``;
+for a ring of nodes (``bench/ring.py``) also ``init_params``, ``grad_fn``,
+``adam`` and ``leaf_specs`` (the wire kernels' rows); and,
+where the family has kernels of its own, ``kernel_counts(conf, traffic)``
+(bench/counts.py).
 
 The configurations state float32 weights, activations, optimizer state and
 accumulation, with float32 matrix products at the TPU's default precision
@@ -38,8 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Model", "leaf_specs", "init_params", "run_reference",
-           "check_program", "matmul_params", "param_count",
+__all__ = ["Model", "leaf_specs", "init_params", "grad_fn", "adam",
+           "run_reference", "check_program", "matmul_params", "param_count",
            "flops_per_token"]
 
 ROW_BLOCK = 1024          # rows of a vocabulary-head block
@@ -247,7 +251,7 @@ def _seq_loss(w, tokens, labels, m: Model, dtype):
     return jnp.sum(nll) / s
 
 
-def _grad_fn(m: Model, precision: str):
+def grad_fn(m: Model, precision: str):
     """``precision``: ``f32`` (the reference) or ``bf16`` (the control)."""
     dtype = DTYPES[precision]
 
@@ -277,7 +281,7 @@ def _grad_fn(m: Model, precision: str):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, donate_argnums=(2, 3))
-def _adam(params, grads, mu, nu, t, lr, b1, b2, eps):
+def adam(params, grads, mu, nu, t, lr, b1, b2, eps):
     t = t + 1
     b1t = 1.0 - b1 ** t.astype(jnp.float32)
     b2t = 1.0 - b2 ** t.astype(jnp.float32)
@@ -305,7 +309,7 @@ def run_reference(m: Model, opt: dict, seed: int,
     (tokens, labels) rows.  Returns the loss of every step, the first
     step's gradient and the change of the parameters over all the steps,
     the last two as host arrays per leaf."""
-    grad = _grad_fn(m, precision)
+    grad = grad_fn(m, precision)
     lr, b1, b2, eps = (np.float32(opt[k]) for k in ("lr", "b1", "b2", "eps"))
     x0 = init_params(m, seed)
     p = x0
@@ -318,7 +322,7 @@ def run_reference(m: Model, opt: dict, seed: int,
         losses.append(loss)
         if grad1 is None:
             grad1 = jax.tree.map(np.asarray, g)
-        p, mu, nu, t = _adam(p, g, mu, nu, t, lr, b1, b2, eps)
+        p, mu, nu, t = adam(p, g, mu, nu, t, lr, b1, b2, eps)
         del g
     change = jax.tree.map(np.asarray, _diff(p, x0))
     return {"loss": [float(v) for v in losses], "grad1": grad1,

@@ -17,7 +17,9 @@ Readings for the limits, without a window, one process for many seeds:
     python3 bench/run.py --workload <cell> --readings 4,5,6 --stand-in control
 
 ``--stand-in`` puts the reference in the program's place: one precision
-lower (``control``), or with a fault planted (``half_batch``).
+lower (``control``), or with a fault planted: half of each node's rows
+left out (``half_batch``), or, on a ring, the exchange between the nodes
+left out (``no_exchange``).
 ``--readings-out FILE`` appends each seed's record, with the per-leaf
 norms its numbers are read from, to FILE.
 """
@@ -47,7 +49,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
     ap.add_argument("--readings", type=_seeds, default=None,
                     help="seeds: the numbers compared, no window")
-    ap.add_argument("--stand-in", choices=["control", "half_batch"],
+    ap.add_argument("--stand-in",
+                    choices=["control", "half_batch", "no_exchange"],
                     default=None,
                     help="with --readings: the reference one precision "
                          "lower, or with a fault planted, in the program's "

@@ -1,7 +1,9 @@
 """Device time of the train step by direction and named scope.
 
-The program runs its work under stable ``jax.named_scope``s (PERF.md §3
-lists them).  XLA keeps each op's scope path in the compiled step's HLO
+The program runs its work under stable ``jax.named_scope``s; the names
+it declares are those its source passes to ``jax.named_scope`` as string
+literals (``declared_scopes``; PERF.md §3 lists them).  XLA keeps each
+op's scope path in the compiled step's HLO
 metadata (``op_name``), and JAX writes there the transform the op belongs
 to: ``jvp(...)`` is the forward pass, ``transpose(jvp(...))`` the
 backward, and ``checkpoint/rematted_computation`` an op recomputed in the
@@ -9,7 +11,10 @@ backward to save memory.  The profiler's device trace names the same ops
 by their HLO instruction names (``fusion.623``).  ``op_names`` reads the
 map between the two from ``compiled.as_text()``; ``reduce`` splits the
 traced window's device self time by (direction, scope) on top of
-``bench.trace.reduce``, whose numbers it leaves as they are.
+``bench.trace.reduce``, whose numbers it leaves as they are.  An op's
+label is the path of declared scopes that ends its own path (``scope``):
+a scope the program opens under a declared one reads as its own label
+(``mlp/router``) with no edit here.
 
     python3 bench/scopes.py --workload <cell> --seed <n> --seconds <s>
 
@@ -28,20 +33,17 @@ import sys
 from collections import defaultdict
 from pathlib import Path
 
+_ROOT = Path(__file__).resolve().parent.parent
 if __name__ == "__main__":
-    _ROOT = Path(__file__).resolve().parent.parent
     sys.path[:0] = [str(_ROOT / "src"), str(_ROOT)]
 
 from bench import trace as TR  # noqa: E402
 
-__all__ = ["SCOPES", "PROGRAM_SPANS", "DIRECTIONS", "op_names", "direction",
-           "scope", "load", "reduce", "ms_per_step"]
+__all__ = ["SOURCE", "PROGRAM_SPANS", "DIRECTIONS", "declared_scopes",
+           "op_names", "direction", "scope", "load", "reduce", "ms_per_step"]
 
-#: the program's named scopes, as paths; an op takes the innermost that
-#: its own path holds, or ``unscoped``
-SCOPES = ("embed", "layers", "attention", "attention/core", "mlp",
-          "head_loss", "optimizer", "exchange", "exchange/noise",
-          "exchange/encode", "exchange/permute", "exchange/combine")
+#: the program's source, whose ``jax.named_scope`` calls declare its scopes
+SOURCE = _ROOT / "src"
 #: host spans the program itself writes on the profiler's clock
 PROGRAM_SPANS = ("input.build", "input.transfer")
 DIRECTIONS = ("fwd", "bwd", "recompute", "update")
@@ -53,6 +55,19 @@ _OPERAND = re.compile(r"%([^\s,)]+)")
 _CALLS = re.compile(r"\b(?:calls|body|condition|to_apply|branch_computations|"
                     r"called_computations)=\{?(%[^}\s]+(?:,\s*%[^}\s,]+)*)")
 _TRANSFORM = re.compile(r"^[\w.-]+\((.*)\)$")
+_NAMED_SCOPE = re.compile(r"named_scope\(\s*[\"']([\w./-]+)[\"']\s*\)")
+
+
+def declared_scopes(source: Path = SOURCE) -> frozenset[str]:
+    """Every scope name the program's source declares: the string literals
+    it passes to ``jax.named_scope``, split at ``/``.  A name made at run
+    time (``f"chunk{c}"``) is not declared, and its component is left out
+    of labels."""
+    names = set()
+    for path in sorted(Path(source).rglob("*.py")):
+        for lit in _NAMED_SCOPE.findall(path.read_text()):
+            names.update(lit.split("/"))
+    return frozenset(names)
 
 
 def op_names(hlo_text: str) -> dict[str, str]:
@@ -126,19 +141,19 @@ def _parts(path: str) -> list[str]:
     return out
 
 
-def scope(path: str) -> str:
-    """The innermost of SCOPES on ``path`` (the one whose last occurrence
-    ends deepest), or ``unscoped``."""
-    parts = _parts(path)
-    best, end = "unscoped", -1
-    for s in SCOPES:
-        words = s.split("/")
-        n = len(words)
-        ends = [i + n for i in range(len(parts) - n + 1)
-                if parts[i:i + n] == words]
-        if ends and ends[-1] > end:
-            best, end = s, ends[-1]
-    return best
+def scope(path: str, declared: frozenset[str]) -> str:
+    """The op's label: the declared scopes that end ``path``, from the last
+    one that is not opened directly inside the one before it, or
+    ``unscoped``.  A scope directly inside another continues its label
+    (``attention/core``, ``exchange/encode``); one opened after other
+    structure (the layer loop's ``while/body``, a call, a remat) starts
+    its own (``layers/while/body/.../attention`` reads ``attention``)."""
+    label, nested = [], False
+    for p in _parts(path):
+        if p in declared:
+            label = label + [p] if nested else [p]
+        nested = p in declared
+    return "/".join(label) or "unscoped"
 
 
 def load(trace_dir: str) -> dict:
@@ -170,9 +185,11 @@ def ms_per_step(obs: dict, keep) -> float | None:
 
 
 def reduce(raw: dict, op_map: dict[str, str] | None = None,
-           chips: int | None = None) -> dict:
+           chips: int | None = None,
+           declared: frozenset[str] | None = None) -> dict:
     """``bench.trace.reduce`` of the record, with, where ``op_map`` is
-    given, ``scopes``: per ``direction/scope``, device self time in the
+    given, ``scopes``: per ``direction/label`` (``scope`` over the
+    ``declared`` names, by default the program's), device self time in the
     window (seconds, averaged over the chips); ``scope_coverage``: the
     share of that time which carries a program scope; ``breakdown``: the
     top ops named ``<op> <direction>/<scope>`` and the idle gaps
@@ -184,12 +201,14 @@ def reduce(raw: dict, op_map: dict[str, str] | None = None,
         return out
     lo, hi = out["lo"], out["hi"]
     devs = sorted(raw["devices"])[:chips] if chips else sorted(raw["devices"])
+    if declared is None:
+        declared = declared_scopes()
     tag = {}
 
     def label(name: str) -> str:
         if name not in tag:
             path = op_map.get(name.split(" ")[0], "")
-            tag[name] = f"{direction(path)}/{scope(path)}"
+            tag[name] = f"{direction(path)}/{scope(path, declared)}"
         return tag[name]
 
     scopes, busy, unscoped = defaultdict(float), {}, defaultdict(float)
@@ -311,6 +330,9 @@ def main(argv=None) -> int:
         "busy_ms_per_step": 1e3 * red["busy_s"] / win["steps"],
         "idle_frac": red["idle_frac"],
         "scope_coverage": red["scope_coverage"], "metrics": metrics,
+        "kernels": {k: {"calls_per_step": v["calls"] / win["steps"],
+                        "ms_per_call": 1e3 * v["seconds"] / v["calls"]}
+                    for k, v in red["kernels"].items() if v["calls"]},
         "scope_ms_per_step": per_step, "breakdown": red["breakdown"],
         "unscoped_ops": red["unscoped_ops"]}),
         flush=True)

@@ -35,14 +35,15 @@ def unchanged(step, sys_):
 
 
 def half_batch(step, sys_):
-    """Half of the rows left out, the mean taken over the rest (the kept
-    half stands in for the rest)."""
+    """Half of each node's rows left out, the mean taken over the rest (the
+    kept half stands in for the rest)."""
     def f(state, batch):
         rows = {}
         for k, v in batch.items():
             a = np.asarray(v).copy()
-            b = len(a) // 2
-            a[b:2 * b] = a[:b]
+            for part in np.split(a, sys_.nodes):
+                b = len(part) // 2
+                part[b:2 * b] = part[:b]
             rows[k] = a
         return step(state, jax.device_put(rows, sys_.setup.batch_sharding))
     return f

@@ -37,6 +37,11 @@ def flops_per_token(conf, seq_len):
 
 def param_count(conf):
     return R.param_count(conf)
+
+
+def kernel_counts(conf, traffic):
+    CALLS.append("kernel_counts")
+    return {"stub_kernel": {"flops": 1.0, "bytes": 2.0}}
 '''
 
 
@@ -56,8 +61,12 @@ def test_stub_reference_module_is_taken_checked_and_counted(stub):
     r = H.run_cell(files, SEED, 0.5, False, time.perf_counter(),
                    H.device_info(1))
     assert r["correct"], r["compared"]
-    assert {"check_program", "run_reference",
-            "flops_per_token"} <= set(mod.CALLS)
+    assert {"check_program", "run_reference", "flops_per_token",
+            "kernel_counts"} <= set(mod.CALLS)
+    # the family's own kernels join the shared ones
+    counted = H.kernel_counts(files)
+    assert counted["stub_kernel"] == {"flops": 1.0, "bytes": 2.0}
+    assert "flash_attn_fwd" in counted
     files["config"]["stub_agrees"] = False
     with pytest.raises(SystemExit):
         H.model_config(files["config"])

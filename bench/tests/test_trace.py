@@ -88,3 +88,25 @@ def test_op_names():
                       'custom_call_target="tpu_custom_call", backend_config='
                       '{"kernel_name": "_payload_fixed_kernel"}') == (
         "custom-call.3 tpu_custom_call _payload_fixed_kernel")
+
+
+def test_kernels_time_and_calls():
+    """Every Pallas kernel's self time and calls in the window, averaged
+    over the chips, by the kernel's name without its instruction's
+    number."""
+    raw = hand_made()
+    raw["devices"][0] += [["flash_attn_fwd.16 tpu_custom_call", 150, 4],
+                          ["flash_attn_fwd.17 tpu_custom_call", 190, 6],
+                          ["flash_attn_fwd.17 tpu_custom_call", 260, 6],
+                          ["custom-call.3 tpu_custom_call int8_encode", 140,
+                           5]]
+    raw["devices"][1] += [["flash_attn_fwd.16 tpu_custom_call", 120, 8]]
+    r = TR.reduce(raw)
+    assert r["kernels"] == {
+        "flash_attn_fwd": {"seconds": pytest.approx((10e-9 + 8e-9) / 2),
+                           "calls": 1.5},
+        "int8_encode": {"seconds": pytest.approx(5e-9 / 2), "calls": 0.5}}
+    assert sum(k["seconds"] for k in r["kernels"].values()) <= r["busy_s"]
+    assert TR.kernel_name("fusion.12") is None
+    assert TR.kernel_name("flash_attn_dq.10 tpu_custom_call") == \
+        "flash_attn_dq"

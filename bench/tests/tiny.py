@@ -6,7 +6,9 @@ from __future__ import annotations
 
 from bench import harness as H
 
-#: program overrides and the matching published keys of a tiny model
+#: program overrides and the matching published keys of a tiny model, by
+#: the configuration's ``arch``; a reference module of another family
+#: defines its own ``TINY``
 TINY = {
     "smollm-135m": ({"d_model": 64, "n_periods": 4, "n_heads": 4,
                      "n_kv_heads": 2, "d_ff": 128, "vocab_size": 512},
@@ -24,26 +26,33 @@ TINY = {
 }
 
 
-#: cells with their configuration and traffic, as BENCHMARK.json names them
-CELLS = {
-    "qwen3-0.6b.chip1.local": ("qwen3-0.6b", "node1-s4096-b1", 1),
-    "smollm-135m.chip1.local": ("smollm-135m", "node1-s2048-b8", 1),
-}
+def cells() -> dict:
+    """The cells of BENCHMARK.json by name."""
+    return {w["name"]: w for w in H.load_json(H.ROOT / "BENCHMARK.json")[
+        "workloads"]}
 
 
-def tiny_files(cell: str, seq_len: int = 128, seqs_per_node: int = 2) -> dict:
-    config, traffic, chips = CELLS[cell]
+def tiny_files(cell, seq_len: int = 128, seqs_per_node: int = 2,
+               limits: dict | None = None) -> dict:
+    """The files of ``cell`` (a name in BENCHMARK.json, or an entry of
+    its own), cut to a tiny model and ``seqs_per_node`` rows of
+    ``seq_len`` tokens a node; ``limits`` in place of the cell's own."""
+    spec = cell if isinstance(cell, dict) else cells()[cell]
+    config = next(c for c in H.load_json(H.ROOT / "BENCHMARK.json")[
+        "configs"] if c["name"] == spec["config"])
     files = {
-        "cell": {"name": cell, "config": config, "traffic": traffic,
-                 "chips": chips},
-        "config": H.load_json(H.BENCH / "configs" / f"{config}.json"),
-        "traffic": H.load_json(H.BENCH / "traffic" / f"{traffic}.json"),
-        "limits": H.load_json(H.BENCH / "workloads" / f"{cell}.json")[
-            "limits"],
+        "cell": dict(spec),
+        "config": H.load_json(H.ROOT / config["file"]),
+        "traffic": H.load_json(H.BENCH / "traffic"
+                               / f"{spec['traffic']}.json"),
+        "limits": limits or H.load_json(
+            H.BENCH / "workloads" / f"{spec['name']}.json")["limits"],
         "end_to_end": [], "per_layer": [],
     }
     conf = files["config"]
-    program, published = TINY[conf["arch"]]
+    # a family's reference module may bring its own tiny sizes
+    program, published = getattr(H.reference_module(conf), "TINY",
+                                 TINY)[conf["arch"]]
     conf["program"] = dict(program)
     conf.update(published)
     tr = files["traffic"]

@@ -4,8 +4,9 @@
 record: per device, the ops of its "XLA Ops" line; the host spans the
 benchmark put around its own calls (``window``, ``input``, ``dispatch``,
 ``readback``).  ``reduce(raw)`` turns that record into the numbers the
-per-layer metrics read.  Times are nanoseconds on the trace's clock, which
-the host and device planes share.
+per-layer metrics read, among them every Pallas kernel's time and calls.
+Times are nanoseconds on the trace's clock, which the host and device
+planes share.
 """
 from __future__ import annotations
 
@@ -14,7 +15,8 @@ import os
 import re
 from collections import defaultdict
 
-__all__ = ["HOST_SPANS", "load", "reduce", "op_name", "self_times", "merge"]
+__all__ = ["HOST_SPANS", "load", "reduce", "op_name", "kernel_name",
+           "self_times", "merge"]
 
 HOST_SPANS = ("window", "input", "dispatch", "readback")
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -59,6 +61,17 @@ def op_name(hlo: str) -> str:
             r'"name"\W+([\w.]+)', hlo)
         name += " tpu_custom_call" + (f" {k.group(1)}" if k else "")
     return name
+
+
+def kernel_name(op: str) -> str | None:
+    """The Pallas kernel an op of the trace runs: the kernel's own name
+    where ``op_name`` found one, else its instruction's name without the
+    ``.N`` suffix (``flash_attn_fwd.16 tpu_custom_call`` ->
+    ``flash_attn_fwd``); None for an op that is no kernel."""
+    words = op.split(" ")
+    if "tpu_custom_call" not in words:
+        return None
+    return words[2] if len(words) > 2 else re.sub(r"\.\d+$", "", words[0])
 
 
 def self_times(ops: list) -> dict:
@@ -108,7 +121,9 @@ def reduce(raw: dict, chips: int | None = None) -> dict:
     """Busy and idle time of the traced window, averaged over the cell's
     chips and taken on the busiest; per-op device self time (averaged over
     the chips); the longest idle gaps of the busiest chip, labelled by the
-    host span they fall in."""
+    host span they fall in; ``kernels``: per Pallas kernel (``kernel_name``),
+    its device self seconds in the window and its calls that start there,
+    both averaged over the chips."""
     windows = [h for h in raw["host"] if h[0] == "window"]
     if not windows:
         raise RuntimeError("the trace holds no window span")
@@ -118,6 +133,7 @@ def reduce(raw: dict, chips: int | None = None) -> dict:
     if not devs:
         raise RuntimeError("the trace holds no device ops")
     busy, per_op, gaps_by_dev = {}, defaultdict(float), {}
+    kernels = defaultdict(lambda: {"seconds": 0.0, "calls": 0.0})
     for d in devs:
         ops = raw["devices"][d]
         spans = merge(_clip([(s, s + du) for _, s, du in ops], lo, hi))
@@ -127,6 +143,11 @@ def reduce(raw: dict, chips: int | None = None) -> dict:
                   for n, s, du in ops if s + du > lo and s < hi]
         for n, t in self_times(inside).items():
             per_op[n] += t / 1e9
+            if (k := kernel_name(n)) is not None:
+                kernels[k]["seconds"] += t / 1e9 / len(devs)
+        for n, s, _ in ops:
+            if lo <= s < hi and (k := kernel_name(n)) is not None:
+                kernels[k]["calls"] += 1 / len(devs)
     busiest = max(devs, key=lambda d: busy[d])
     window_s = dur / 1e9
     gaps = sorted(gaps_by_dev[busiest], key=lambda g: g[0] - g[1])[:TOP]
@@ -143,6 +164,7 @@ def reduce(raw: dict, chips: int | None = None) -> dict:
         "host_spans": {name: [d / 1e9 for n, s, d in host
                               if n == name and lo <= s < hi]
                        for name in HOST_SPANS if name != "window"},
+        "kernels": dict(sorted(kernels.items())),
         "lo": lo, "hi": hi,
     }
 
